@@ -394,15 +394,13 @@ def bench_cache(P: int = 8, n: int = 64, batches: int = 8,
 # multi-op txn mix — contention priced as the measured abort rate, both
 # one-sided and AM commit arms on the IDENTICAL stream.
 # ---------------------------------------------------------------------------
-def bench_txn(P: int = 8, nops: int = 4, batches: int = 6,
-              alpha: float = 1.1, nkeys: int = 24, L: int = 256,
-              seed: int = 23):
-    """Returns a row dict: µs per transaction for the rdma_fused and am
-    commit arms on a zipfian hot-key txn mix (every rank runs one
-    `nops`-op txn per batch; keys drawn zipf(alpha) from `nkeys` hot
-    words, so write sets collide and the OCC lock phase aborts/retries),
-    plus the measured abort rate, median round count, stamp-served read
-    count, and a per-batch serial-oracle conformance verdict."""
+def hot_key_txn_mix(P: int = 8, nops: int = 4, batches: int = 6,
+                    alpha: float = 1.1, nkeys: int = 24, L: int = 256,
+                    seed: int = 23):
+    """The hot-key transaction mix: (initial (P, L) window, one Txn per
+    batch). Every rank runs one `nops`-op txn per batch; keys are drawn
+    zipf(alpha) from `nkeys` hot words owned by rank 0's key set, so
+    write sets collide and the OCC lock phase aborts and retries."""
     from repro.core import txn as txn_mod
 
     rng = np.random.default_rng(seed)
@@ -429,6 +427,20 @@ def bench_txn(P: int = 8, nops: int = 4, batches: int = 6,
                 t.fao(dst, off, brng.integers(-3, 4, P))
         return t
 
+    return init, [stage(b) for b in range(batches)]
+
+
+def bench_txn(P: int = 8, nops: int = 4, batches: int = 6,
+              alpha: float = 1.1, nkeys: int = 24, L: int = 256,
+              seed: int = 23):
+    """Returns a row dict: µs per transaction for the rdma_fused and am
+    commit arms on the hot-key txn mix (`hot_key_txn_mix`), plus the
+    measured abort rate, median round count, stamp-served read count,
+    and a per-batch serial-oracle conformance verdict."""
+    from repro.core import txn as txn_mod
+
+    init, txns = hot_key_txn_mix(P, nops, batches, alpha, nkeys, L, seed)
+
     row = {"P": P, "nops": nops, "batches": batches, "alpha": alpha,
            "nkeys": nkeys, "conformant": True}
     for arm in ("rdma_fused", "am"):
@@ -437,8 +449,7 @@ def bench_txn(P: int = 8, nops: int = 4, batches: int = 6,
         aborts = attempts = rounds_sum = saved = 0
         rounds_all = []
         t0 = time.perf_counter()
-        for b in range(batches):
-            t = stage(b)
+        for t in txns:
             st0 = {"ht": np.asarray(win.data).copy()}
             res = eng.run(win, t, arm=arm)
             win = res.wins["ht"]
@@ -691,6 +702,8 @@ def smoke_txn(P: int = 8, abort_ceiling: float = 0.9,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if "--smoke-coalesce" in sys.argv:
         sys.exit(0 if smoke_coalesce() else 1)
     if "--smoke-cache" in sys.argv:

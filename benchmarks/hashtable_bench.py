@@ -164,6 +164,8 @@ def smoke(P: int = 8, n: int = 64, iters: int = 9) -> bool:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if "--smoke" in sys.argv:
         sys.exit(0 if smoke() else 1)
     main()

@@ -358,6 +358,8 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if "--smoke" in sys.argv:
         sys.exit(0 if smoke() else 1)
     main()

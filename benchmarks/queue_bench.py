@@ -117,4 +117,6 @@ def main(out="artifacts/bench"):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

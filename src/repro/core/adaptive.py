@@ -54,7 +54,7 @@ import numpy as np
 from . import costmodel as cm
 from . import faults as flt
 from .costmodel import ARMS, ComponentCosts, DSOp
-from .types import OpStats, Promise
+from .types import OpStats, Promise, host_value
 
 
 @dataclass(frozen=True)
@@ -89,16 +89,6 @@ class Decision:
                                   # degradation; source == "quarantine")
 
 
-def _concrete(x) -> Optional[np.ndarray]:
-    """Host value of `x`, or None under jit tracing."""
-    if x is None:
-        return None
-    try:
-        return np.asarray(x)
-    except Exception:  # TracerArrayConversionError and friends
-        return None
-
-
 def batch_dedup(keys, valid=None) -> float:
     """Distinct-row fraction of a batch: unique key rows / total rows —
     the third online signal (DESIGN.md §6), computed host-side like
@@ -106,10 +96,10 @@ def batch_dedup(keys, valid=None) -> float:
     whose duplicate identity is the key (the hash-table front-ends: equal
     keys place identically, so they share (owner, offset) every probe).
     Returns 1.0 (no duplicates assumed) when `keys` is a tracer."""
-    k = _concrete(keys)
+    k = host_value(keys)
     if k is None:
         return 1.0
-    v = _concrete(valid)
+    v = host_value(valid)
     flat = k.ravel() if v is None else k[v.astype(bool)].ravel()
     if flat.size == 0:
         return 1.0
@@ -123,10 +113,10 @@ def batch_skew(dst, nranks: int, valid=None) -> float:
     host-side with a bincount — the same statistic `routing.plan_skew`
     derives from a RoutePlan's exchanged occupancy mask, without paying the
     plan's occupancy exchange. Returns 1.0 when `dst` is a tracer."""
-    d = _concrete(dst)
+    d = host_value(dst)
     if d is None:
         return 1.0
-    v = _concrete(valid)
+    v = host_value(valid)
     flat = d.ravel() if v is None else d[v.astype(bool)].ravel()
     if flat.size == 0:
         return 1.0
@@ -559,7 +549,7 @@ class AdaptiveEngine:
             skew = batch_skew(dst, self.nranks, valid)
         dedup = s.dedup
         if nops is None:
-            v = _concrete(valid)
+            v = host_value(valid)
             if v is not None:
                 nops = int(v.sum())
             elif dst is not None and not isinstance(dst, jax.core.Tracer):
@@ -610,10 +600,10 @@ class AdaptiveEngine:
             if owners is not None:
                 hit = any(int(r) in self.quarantined for r in owners)
             else:
-                d = _concrete(dst)
+                d = host_value(dst)
                 hit = False
                 if d is not None:
-                    v = _concrete(valid)
+                    v = host_value(valid)
                     flat = (d.ravel() if v is None
                             else d[v.astype(bool)].ravel())
                     hit = bool(np.isin(
@@ -650,10 +640,10 @@ class AdaptiveEngine:
             return fn()
         t0 = time.perf_counter()
         out = fn()
-        try:
-            jax.block_until_ready(out)
-        except Exception:
+        if any(isinstance(x, jax.core.Tracer)
+               for x in jax.tree_util.tree_leaves(out)):
             return out  # traced values: skip the observation
+        jax.block_until_ready(out)
         us = (time.perf_counter() - t0) * 1e6 / dec.batch_ops
         if dec.arm == "am_pt":
             us *= self.params.pt_overhead

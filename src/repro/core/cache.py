@@ -68,19 +68,11 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from .types import host_value
+
 # Tag sentinel for an empty line. Keys are int32; the tag array is int64 so
 # no valid key can collide with the sentinel.
 _EMPTY_TAG = np.int64(1) << 40
-
-
-def _concrete(x) -> Optional[np.ndarray]:
-    """Host value of `x`, or None under jit tracing."""
-    if x is None:
-        return None
-    try:
-        return np.asarray(x)
-    except Exception:  # TracerArrayConversionError and friends
-        return None
 
 
 def _is_ready(x) -> bool:
@@ -176,13 +168,13 @@ class BucketCache:
         the tolerance are still evicted."""
         if not self.enabled:
             return None
-        k = _concrete(keys)
+        k = host_value(keys)
         if k is None:
             return None
         if valid is None:
             v = np.ones(k.shape, bool)
         else:
-            v = _concrete(valid)
+            v = host_value(valid)
             if v is None:
                 return None
             v = v.astype(bool)
@@ -335,13 +327,13 @@ class BucketCache:
         versions of every key about to be written (any arm — the AM
         insert-or-assign included). Tracer batches invalidate everything."""
         self.write_tick += 1
-        k = _concrete(keys)
+        k = host_value(keys)
         if k is None:
             self.invalidate_all(bump_tick=False)
             return
         v = None
         if valid is not None:
-            v = _concrete(valid)
+            v = host_value(valid)
             if v is None:
                 self.invalidate_all(bump_tick=False)
                 return
@@ -365,12 +357,12 @@ class BucketCache:
         bump exactly the flipped slot (off is the flag-word offset, so
         slot = off // rec_w). Tracers no-op — `on_insert_keys` is the
         authoritative channel (see module docstring)."""
-        d, o = _concrete(dst), _concrete(off)
+        d, o = host_value(dst), host_value(off)
         if d is None or o is None:
             return
         self.write_tick += 1
         if valid is not None:
-            v = _concrete(valid)
+            v = host_value(valid)
             if v is None:
                 self.invalidate_all(bump_tick=False)
                 return

@@ -52,6 +52,8 @@ from typing import Dict, Optional, Set, Tuple
 
 import numpy as np
 
+from .types import host_value
+
 __all__ = ["RemoteTimeout", "RetryPolicy", "DedupIndex", "FaultPlan",
            "fault_scope", "active_plane"]
 
@@ -109,16 +111,6 @@ def _uniform(seed: int, salt: int, phase: int, attempt: int,
         h = (h ^ (h >> np.uint64(27))) * _K[2]
         h = h ^ (h >> np.uint64(31))
     return (h >> np.uint64(11)).astype(np.float64) / float(1 << 53)
-
-
-def _concrete(x) -> Optional[np.ndarray]:
-    """Host array, or None for a jit tracer (adaptive._concrete idiom)."""
-    if x is None:
-        return None
-    try:
-        return np.asarray(x)
-    except Exception:
-        return None
 
 
 # ---------------------------------------------------------------------------
@@ -356,12 +348,12 @@ class FaultPlan:
         self.phase_idx += 1
         if not self._wire_faults:
             return valid
-        dst_np = _concrete(dst)
+        dst_np = host_value(dst)
         if dst_np is None or dst_np.ndim != 2:
             return valid  # symbolic dst: never happens in the engine
         self._accommodate(dst_np)
         P, n = dst_np.shape
-        valid_np = _concrete(valid)
+        valid_np = host_value(valid)
         active = (np.ones((P, n), dtype=bool) if valid_np is None
                   else valid_np.astype(bool))
         np.add.at(self.owner_rows,
@@ -382,12 +374,12 @@ class FaultPlan:
         the same wire retransmit+dedup simulation as one-sided phases."""
         phase = self.phase_idx
         self.phase_idx += 1
-        dst_np = _concrete(dst)
+        dst_np = host_value(dst)
         if dst_np is None or dst_np.ndim != 2:
             return valid
         self._accommodate(dst_np)
         P, n = dst_np.shape
-        valid_np = _concrete(valid)
+        valid_np = host_value(valid)
         active = (np.ones((P, n), dtype=bool) if valid_np is None
                   else valid_np.astype(bool))
         clip = np.clip(dst_np, 0, self.nranks - 1)

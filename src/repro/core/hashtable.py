@@ -67,9 +67,13 @@ class DHashTable:
         return 2 + self.val_words
 
 
-def make_hashtable(nranks: int, nslots: int, val_words: int) -> DHashTable:
+def make_hashtable(nranks: int, nslots: int, val_words: int,
+                   sharding=None) -> DHashTable:
+    """Empty table of `nslots` records per rank; `sharding` places the
+    window's rank rows (see `window.make_window`)."""
     rec_w = 2 + val_words
-    return DHashTable(win=win_mod.make_window(nranks, nslots * rec_w),
+    return DHashTable(win=win_mod.make_window(nranks, nslots * rec_w,
+                                              sharding=sharding),
                       nslots=nslots, val_words=val_words)
 
 
@@ -716,9 +720,9 @@ def _async_stats(ht, keys, valid, stats, depth: int):
     import numpy as np
 
     from . import adaptive as ad
-    from .types import OpStats
+    from .types import OpStats, host_value
     s = stats or OpStats()
-    k = ad._concrete(keys)
+    k = host_value(keys)
     if k is not None:
         # 1.0 doubles as OpStats' "unknown" sentinel: nudge a legitimately
         # computed 1.0 (perfectly uniform / all-distinct batch) off it by

@@ -94,17 +94,14 @@ class Handle:
 
         Never blocks: a deferred batch still waiting for a dispatch point
         reports False, as does a staged batch whose device work is in
-        flight (falls back to True-once-staged where the runtime lacks
-        `is_ready`)."""
+        flight. A device error surfaces from `is_ready` (or from
+        `result()`), never as a finished batch."""
         if self._forced or self._error is not None:
             return True
         if not self._staged:
             return False
-        try:
-            return all(x.is_ready() for x in jax.tree_util.tree_leaves(
-                self._outputs) if hasattr(x, "is_ready"))
-        except Exception:
-            return True
+        return all(x.is_ready() for x in jax.tree_util.tree_leaves(
+            self._outputs) if hasattr(x, "is_ready"))
 
     def result(self, timeout: Optional[int] = None) -> Any:
         """Force completion and return the batch's outputs.
@@ -184,30 +181,35 @@ class Pipeline:
         return False
 
     def close(self) -> None:
-        """Best-effort teardown: drain the deferred queue so no dispatch
-        thunk is stranded, force every stageable Handle, and fail the
-        rest with `faults.RemoteTimeout`. Errors are swallowed (this is
-        the exception path of the context manager); queued thunks of this
-        pipeline become no-ops, so a later engine drain by another user
-        cannot resurrect a batch the caller was told had failed."""
+        """Teardown that strands no batch: drain the deferred queue so no
+        dispatch thunk is stranded, force every stageable Handle, and fail
+        the rest with `faults.RemoteTimeout`. It raises nothing (this is
+        the exception path of the context manager): an error met on the
+        way is kept on its Handle, whose `result()` raises it. Queued
+        thunks of this pipeline become no-ops, so a later engine drain by
+        another user cannot resurrect a batch the caller was told had
+        failed."""
+        drain_error = None
         try:
             self._drain_deferred()
-        except Exception:
-            pass
+        except Exception as e:  # kept on the unserviced handles below
+            drain_error = e
         for h in list(self._inflight):
             if h._staged:
                 try:
                     self._force(h)
-                except Exception:
-                    pass
+                    continue
+                except Exception as e:  # result() raises it
+                    h._error = e
             else:
                 h._error = flt.RemoteTimeout(
                     f"pipeline closed with batch seq={h.seq} "
                     f"({h.label or 'op'}) never serviced")
-                try:
-                    self._inflight.remove(h)
-                except ValueError:
-                    pass
+                h._error.__cause__ = drain_error
+            try:
+                self._inflight.remove(h)
+            except ValueError:
+                pass
         self._closed = True
         self._own_queue.clear()
         self._note_inflight()

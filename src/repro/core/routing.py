@@ -27,6 +27,8 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..kernels.scan import segmented_scan
+
 # ---------------------------------------------------------------------------
 # Sharding hook: the launch layer installs a constraint function so that the
 # P axis stays pinned to its mesh axes across phases (forcing all_to_all
@@ -369,11 +371,11 @@ def route_replies(routed: Routed, replies: jax.Array, dst: jax.Array,
 # formula, the leader's reply for gets/puts).
 # ---------------------------------------------------------------------------
 def _suffix_min(x: jax.Array) -> jax.Array:
-    return jnp.flip(jax.lax.associative_scan(jnp.minimum, jnp.flip(x)))
+    return jax.lax.cummin(x, reverse=True)
 
 
 def _prefix_max(x: jax.Array) -> jax.Array:
-    return jax.lax.associative_scan(jnp.maximum, x)
+    return jax.lax.cummax(x)
 
 
 @functools.partial(jax.tree_util.register_dataclass,
@@ -479,13 +481,7 @@ def coalesce_fold(co: Coalescing, operand: jax.Array, binop,
 
     def one(order, run_first, op_r):
         op_s = op_r[order]
-
-        def comb(a, b):
-            af, av = a
-            bf, bv = b
-            return af | bf, jnp.where(bf, bv, binop(av, bv))
-
-        _, incl = jax.lax.associative_scan(comb, (run_first, op_s))
+        incl = segmented_scan(binop, run_first, op_s)
         ident = jnp.full_like(op_s, identity)
         excl = jnp.where(run_first, ident, jnp.roll(incl, 1))
         idx = jnp.arange(n, dtype=jnp.int32)

@@ -11,9 +11,23 @@ from __future__ import annotations
 
 import enum
 from dataclasses import dataclass
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+
+def host_value(x) -> Optional[np.ndarray]:
+    """Host copy of `x`, or None when `x` is absent or a jit tracer.
+
+    The front-ends read batch signals (skew, dedup, cache keys) on the
+    host when the batch is concrete and degrade to static choices under
+    tracing. Any other failure to read a device value (a failed or
+    out-of-memory computation) propagates."""
+    if x is None or isinstance(x, jax.core.Tracer):
+        return None
+    return np.asarray(x)
 
 
 class Promise(enum.Enum):

@@ -41,7 +41,8 @@ import jax.numpy as jnp
 
 from . import faults as flt
 from . import routing
-from .types import AmoKind
+from ..kernels.scan import segmented_scan
+from .types import AmoKind, host_value
 
 Array = jax.Array
 
@@ -181,12 +182,10 @@ def drain_phase_log() -> List[Tuple[str, object, Optional[dict]]]:
 def _coalesce_info(co: Optional[routing.Coalescing]) -> Optional[dict]:
     if co is None:
         return None
-    try:
-        import numpy as np
-        ri = int(np.asarray(co.rows_in).sum())
-        ro = int(np.asarray(co.rows_out).sum())
-    except Exception:  # tracers: stats stay on-device
+    rows_in, rows_out = host_value(co.rows_in), host_value(co.rows_out)
+    if rows_in is None or rows_out is None:  # tracers: stats stay on-device
         return {"coalesced": True}
+    ri, ro = int(rows_in.sum()), int(rows_out.sum())
     return {"coalesced": True, "rows_in": ri, "rows_out": ro,
             "dedup_ratio": ro / max(ri, 1)}
 
@@ -209,8 +208,12 @@ class Window:
 
 
 def make_window(nranks: int, local_size: int, dtype=jnp.int32,
-                fill=0) -> Window:
-    return Window(data=jnp.full((nranks, local_size), fill, dtype=dtype))
+                fill=0, sharding=None) -> Window:
+    """A (nranks, local_size) window. `sharding` places the rank rows
+    across devices (e.g. a NamedSharding over a mesh axis); by default
+    the window sits on the default device."""
+    return Window(data=jnp.full((nranks, local_size), fill, dtype=dtype,
+                                device=sharding))
 
 
 # ---------------------------------------------------------------------------
@@ -229,14 +232,7 @@ def _segmented_combine(off_sorted, vals_sorted, init_vals, binop, identity):
     is_last = jnp.concatenate([off_sorted[1:] != off_sorted[:-1],
                                jnp.array([True])])
 
-    # Segmented inclusive scan via associative_scan on (reset_flag, value).
-    def combine(a, b):
-        a_flag, a_val = a
-        b_flag, b_val = b
-        out_val = jnp.where(b_flag, b_val, binop(a_val, b_val))
-        return a_flag | b_flag, out_val
-
-    _, incl = jax.lax.associative_scan(combine, (is_first, vals_sorted))
+    incl = segmented_scan(binop, is_first, vals_sorted)
     ident = jnp.full_like(vals_sorted, identity)
     excl = jnp.where(is_first, ident, jnp.roll(incl, 1))
     old = binop(init_vals, excl)
@@ -620,12 +616,8 @@ def apply_cas_put_local(local: Array, off: Array, cmp: Array, new: Array,
     # flag value at each offset's last slot
     flip_contrib = jnp.where(win_s, flip[order], 0)
 
-    def seg_xor(a, b):
-        a_flag, a_val = a
-        b_flag, b_val = b
-        return a_flag | b_flag, jnp.where(b_flag, b_val, a_val ^ b_val)
-
-    _, xor_incl = jax.lax.associative_scan(seg_xor, (is_first, flip_contrib))
+    xor_incl = segmented_scan(jnp.bitwise_xor, is_first,
+                                      flip_contrib)
     flag_final = val_s ^ xor_incl
     new_local = local.at[jnp.where(is_last, off_s, L)].set(flag_final,
                                                            mode="drop")

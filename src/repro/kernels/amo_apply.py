@@ -20,19 +20,25 @@ Grid: one program per owner row (the P axis); within a program a sequential
 fori_loop walks the op list — atomics are *inherently* serial at the memory
 controller, so the loop order is the semantics, not a perf bug. The local
 window lives in VMEM for the whole batch (one HBM read + one write total),
-which is the TPU-native win over per-op HBM round trips.
+which is the TPU-native win over per-op HBM round trips. The shard is
+tiled and each word is reached through its (8, 128) tile (`tiles.py`);
+op lists, masks and replies are scalar streams in SMEM.
 
-Note on indexing: every `pl.load`/`pl.store` index is a `pl.ds` slice —
-mixing bare scalar ints into the index tuple breaks interpret-mode state
-discharge (`'int' object has no attribute 'shape'`).
+`interpret=None` interprets only on the CPU backend, so a TPU run always
+compiles the kernel.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .scan import inclusive_scan
+from .tiles import (from_tiles, read_word, resolve_interpret, smem_spec,
+                    tile_spec, to_tiles, write_word)
 
 OP_PUT, OP_GET, OP_CAS, OP_FAA, OP_FOR, OP_FAND, OP_FXOR = range(7)
 OP_CAS_PUT, OP_CAS_PUT_PUB, OP_FAO_GET = 7, 8, 9
@@ -62,6 +68,7 @@ def _fao_merge(code, x, y):
         [x + y, x | y, x & y, x ^ y], y)
 
 
+@jax.jit
 def combine_runs(ops, mask):
     """Merge duplicate runs of one shard's serialized op list.
 
@@ -79,19 +86,17 @@ def combine_runs(ops, mask):
     same = same & (~is_cas[1:] | ((a[1:] == a[:-1]) & (b[1:] == b[:-1])))
     run_first = jnp.concatenate([jnp.array([True]), ~same])
     idx = jnp.arange(m, dtype=jnp.int32)
-    run_start = jax.lax.associative_scan(
-        jnp.maximum, jnp.where(run_first, idx, -1))
+    run_start = jax.lax.cummax(jnp.where(run_first, idx, -1))
 
     def comb(x, y):
         xf, xa, _ = x
         yf, ya, yc = y
         return xf | yf, jnp.where(yf, ya, _fao_merge(yc, xa, ya)), yc
 
-    _, incl, _ = jax.lax.associative_scan(comb, (run_first, a, code))
+    _, incl, _ = inclusive_scan(comb, (run_first, a, code))
     excl = jnp.where(run_first, _fao_identity(code), jnp.roll(incl, 1))
     run_last = jnp.concatenate([run_first[1:], jnp.array([True])])
-    end = jnp.flip(jax.lax.associative_scan(
-        jnp.minimum, jnp.flip(jnp.where(run_last, idx, m - 1))))
+    end = jax.lax.cummin(jnp.where(run_last, idx, m - 1), reverse=True)
     is_fao = ((code == OP_FAA) | (code == OP_FOR) | (code == OP_FAND)
               | (code == OP_FXOR))
     a2 = jnp.where(run_first & is_fao, incl[end], a)
@@ -101,6 +106,7 @@ def combine_runs(ops, mask):
     return ops2, mask2, run_start, excl
 
 
+@jax.jit
 def reconstruct_runs(ops, mask, run_start, prefix, old_rep):
     """Per-op old values from the representatives' fetched values.
 
@@ -123,159 +129,159 @@ def reconstruct_runs(ops, mask, run_start, prefix, old_rep):
     return jnp.where(mask, old, 0)
 
 
+def _new_value(code, cur, a, b):
+    """Word value after a primitive op (codes 0-6) or a fused op's atomic
+    component (CAS_PUT[_PUB]: CAS; FAO_GET: fetch-and-op of sub-kind b)."""
+    def fao(kind):
+        return jnp.where(kind == OP_FAA, cur + a,
+                         jnp.where(kind == OP_FOR, cur | a,
+                                   jnp.where(kind == OP_FAND, cur & a,
+                                             jnp.where(kind == OP_FXOR,
+                                                       cur ^ a, cur))))
+    cas = jnp.where(cur == a, b, cur)
+    is_cas = ((code == OP_CAS) | (code == OP_CAS_PUT)
+              | (code == OP_CAS_PUT_PUB))
+    return jnp.where(code == OP_PUT, b,
+                     jnp.where(is_cas, cas,
+                               jnp.where(code == OP_FAO_GET, fao(b),
+                                         fao(code))))
+
+
 def _amo_kernel(local_ref, ops_ref, mask_ref, old_ref, out_ref):
-    # local_ref: (1, L) VMEM; ops_ref: (1, m, 4); mask_ref: (1, m)
+    # local_ref/out_ref: (T, 8, 128) VMEM; ops_ref: (1, 4m) SMEM rows
+    # [off|opcode|a|b]; mask_ref/old_ref: (1, m) SMEM
     out_ref[...] = local_ref[...]
-    m = ops_ref.shape[1]
+    m = mask_ref.shape[1]
 
     def body(j, _):
-        op = ops_ref[0, j]
-        off, code, a, b = op[0], op[1], op[2], op[3]
+        off, code = ops_ref[0, 4 * j], ops_ref[0, 4 * j + 1]
+        a, b = ops_ref[0, 4 * j + 2], ops_ref[0, 4 * j + 3]
         ok = mask_ref[0, j] != 0
-        safe = jnp.where(ok, off, 0)
-        cur = pl.load(out_ref, (pl.ds(0, 1), pl.ds(safe, 1)))[0, 0]
-        new = jnp.select(
-            [code == OP_PUT, code == OP_GET, code == OP_CAS, code == OP_FAA,
-             code == OP_FOR, code == OP_FAND, code == OP_FXOR],
-            [b, cur, jnp.where(cur == a, b, cur), cur + a,
-             cur | a, cur & a, cur ^ a], cur)
-        pl.store(out_ref, (pl.ds(0, 1), pl.ds(safe, 1)),
-                 jnp.where(ok, new, cur)[None, None])
-        pl.store(old_ref, (pl.ds(0, 1), pl.ds(j, 1)),
-                 jnp.where(ok, cur, 0)[None, None])
+        cur = read_word(out_ref, jnp.where(ok, off, 0))
+
+        @pl.when(ok)
+        def _():
+            write_word(out_ref, off, _new_value(code, cur, a, b))
+
+        old_ref[0, j] = jnp.where(ok, cur, 0)
         return 0
 
     jax.lax.fori_loop(0, m, body, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def amo_apply(local: jax.Array, ops: jax.Array, mask: jax.Array,
-              *, interpret: bool = True):
+              *, interpret: Optional[bool] = None):
     """Apply serialized AMO batches to each owner's shard.
 
     local (P, L) int32; ops (P, m, 4) rows [off|opcode|a|b]; mask (P, m).
     Returns (old (P, m), local' (P, L)).
     """
+    return _amo_apply(local, ops, mask,
+                      interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _amo_apply(local, ops, mask, *, interpret):
     P, L = local.shape
     m = ops.shape[1]
-    old, new_local = pl.pallas_call(
+    tiles = to_tiles(local)
+    T = tiles.shape[1]
+    old, new_tiles = pl.pallas_call(
         _amo_kernel,
         grid=(P,),
-        in_specs=[
-            pl.BlockSpec((1, L), lambda i: (i, 0)),
-            pl.BlockSpec((1, m, 4), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, m), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, m), lambda i: (i, 0)),
-            pl.BlockSpec((1, L), lambda i: (i, 0)),
-        ],
+        in_specs=[tile_spec(T), smem_spec(4 * m), smem_spec(m)],
+        out_specs=[smem_spec(m), tile_spec(T)],
         out_shape=[
-            jax.ShapeDtypeStruct((P, m), jnp.int32),
-            jax.ShapeDtypeStruct((P, L), jnp.int32),
+            jax.ShapeDtypeStruct((P, 1, m), jnp.int32),
+            jax.ShapeDtypeStruct(tiles.shape, jnp.int32),
         ],
         interpret=interpret,
-    )(local, ops, mask.astype(jnp.int32))
-    return old, new_local
+    )(tiles, ops.astype(jnp.int32).reshape(P, 1, 4 * m),
+      mask.astype(jnp.int32).reshape(P, 1, m))
+    return old.reshape(P, m), from_tiles(new_tiles, L)
 
 
 def _fused_kernel(local_ref, ops_ref, mask_ref, reply_ref, out_ref,
-                  *, val_words, gather_words):
-    # local_ref: (1, L); ops_ref: (1, m, 6 + V); mask_ref: (1, m);
-    # reply_ref: (1, m, 1 + G); out_ref: (1, L)
+                  *, L, val_words, gather_words):
+    # local_ref/out_ref: (T, 8, 128) VMEM; ops_ref: (1, m*(6+V)) SMEM rows
+    # [off|opcode|a|b|aux0|aux1|vals...]; mask_ref: (1, m) SMEM;
+    # reply_ref: (1, m*(1+G)) SMEM rows [old|gathered...]
     #
     # Sub-phase decomposed semantics (same spec as ref.fused_apply): one
     # serialized pass per sub-phase — atomics, compound puts, publish
     # flips, phase-end gathers.
     out_ref[...] = local_ref[...]
-    m = ops_ref.shape[1]
-    L = local_ref.shape[1]
+    m = mask_ref.shape[1]
     V, G = val_words, gather_words
+    W, RW = 6 + V, 1 + G
+
+    def field(j, k):
+        return ops_ref[0, W * j + k]
 
     def is_csp(code):
         return (code == OP_CAS_PUT) | (code == OP_CAS_PUT_PUB)
 
     def atomic_body(j, _):
-        op = ops_ref[0, j]
-        off, code, a, b = op[0], op[1], op[2], op[3]
+        off, code, a, b = field(j, 0), field(j, 1), field(j, 2), field(j, 3)
         ok = mask_ref[0, j] != 0
-        safe = jnp.where(ok, off, 0)
-        cur = pl.load(out_ref, (pl.ds(0, 1), pl.ds(safe, 1)))[0, 0]
-        win = cur == a
-        new = jnp.select(
-            [code == OP_PUT, code == OP_GET, code == OP_CAS, code == OP_FAA,
-             code == OP_FOR, code == OP_FAND, code == OP_FXOR,
-             is_csp(code),
-             code == OP_FAO_GET],
-            [b, cur, jnp.where(win, b, cur), cur + a,
-             cur | a, cur & a, cur ^ a,
-             jnp.where(win, b, cur),
-             jnp.select([b == OP_FAA, b == OP_FOR, b == OP_FAND,
-                         b == OP_FXOR],
-                        [cur + a, cur | a, cur & a, cur ^ a], cur)], cur)
-        pl.store(out_ref, (pl.ds(0, 1), pl.ds(safe, 1)),
-                 jnp.where(ok, new, cur)[None, None])
-        pl.store(reply_ref, (pl.ds(0, 1), pl.ds(j, 1), pl.ds(0, 1)),
-                 jnp.where(ok, cur, 0)[None, None, None])
+        cur = read_word(out_ref, jnp.where(ok, off, 0))
+
+        @pl.when(ok)
+        def _():
+            write_word(out_ref, off, _new_value(code, cur, a, b))
+
+        reply_ref[0, RW * j] = jnp.where(ok, cur, 0)
         return 0
 
     jax.lax.fori_loop(0, m, atomic_body, 0)
 
     def won(j):
-        # recompute CAS success from the recorded old value
-        op = ops_ref[0, j]
-        ok = mask_ref[0, j] != 0
-        old = pl.load(reply_ref, (pl.ds(0, 1), pl.ds(j, 1),
-                                  pl.ds(0, 1)))[0, 0, 0]
-        return ok & (old == op[2])
+        # CAS success, recomputed from the recorded old value
+        return (mask_ref[0, j] != 0) & (reply_ref[0, RW * j] == field(j, 2))
 
     if V > 0:
         def put_body(j, _):
-            op = ops_ref[0, j]
-            aux0 = op[4]
+            aux0 = field(j, 4)
             # compound payloads are dropped whole when out of range
-            do = (won(j) & is_csp(op[1])
+            do = (won(j) & is_csp(field(j, 1))
                   & (aux0 >= 0) & (aux0 <= L - V))
-            safe_put = jnp.where(do, aux0, 0)
-            cur_v = pl.load(out_ref, (pl.ds(0, 1), pl.ds(safe_put, V)))
-            vals = pl.load(ops_ref, (pl.ds(0, 1), pl.ds(j, 1),
-                                     pl.ds(6, V)))[0]
-            pl.store(out_ref, (pl.ds(0, 1), pl.ds(safe_put, V)),
-                     jnp.where(do, vals, cur_v))
+
+            @pl.when(do)
+            def _():
+                for k in range(V):
+                    write_word(out_ref, aux0 + k, field(j, 6 + k))
+
             return 0
 
         jax.lax.fori_loop(0, m, put_body, 0)
 
     def flip_body(j, _):
-        op = ops_ref[0, j]
-        do = won(j) & (op[1] == OP_CAS_PUT_PUB)
-        safe = jnp.where(do, op[0], 0)
-        cur = pl.load(out_ref, (pl.ds(0, 1), pl.ds(safe, 1)))[0, 0]
-        pl.store(out_ref, (pl.ds(0, 1), pl.ds(safe, 1)),
-                 jnp.where(do, cur ^ op[5], cur)[None, None])
+        off = field(j, 0)
+
+        @pl.when(won(j) & (field(j, 1) == OP_CAS_PUT_PUB))
+        def _():
+            write_word(out_ref, off, read_word(out_ref, off) ^ field(j, 5))
+
         return 0
 
     jax.lax.fori_loop(0, m, flip_body, 0)
 
     if G > 0:
         def gather_body(j, _):
-            op = ops_ref[0, j]
-            aux0 = op[4]
-            ok = mask_ref[0, j] != 0
-            is_get = (ok & (op[1] == OP_FAO_GET)
+            aux0 = field(j, 4)
+            is_get = ((mask_ref[0, j] != 0) & (field(j, 1) == OP_FAO_GET)
                       & (aux0 >= 0) & (aux0 <= L - G))
-            safe_get = jnp.where(is_get, aux0, 0)
-            g = pl.load(out_ref, (pl.ds(0, 1), pl.ds(safe_get, G)))
-            pl.store(reply_ref, (pl.ds(0, 1), pl.ds(j, 1), pl.ds(1, G)),
-                     jnp.where(is_get, g, 0)[:, None, :])
+            src = jnp.where(is_get, aux0, 0)
+            for k in range(G):
+                g = read_word(out_ref, src + k)
+                reply_ref[0, RW * j + 1 + k] = jnp.where(is_get, g, 0)
             return 0
 
         jax.lax.fori_loop(0, m, gather_body, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("reply_width", "interpret"))
 def fused_apply(local: jax.Array, ops: jax.Array, mask: jax.Array,
-                *, reply_width: int, interpret: bool = True):
+                *, reply_width: int, interpret: Optional[bool] = None):
     """Apply serialized fused-descriptor batches to each owner's shard.
 
     local (P, L) int32; ops (P, m, 6 + V) rows
@@ -284,27 +290,28 @@ def fused_apply(local: jax.Array, ops: jax.Array, mask: jax.Array,
     old value at `off`, words 1.. are the FAO_GET gather (zeros otherwise).
     Same contract as kernels/ref.py:fused_apply, validated against it.
     """
+    return _fused_apply(local, ops, mask, reply_width=reply_width,
+                        interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("reply_width", "interpret"))
+def _fused_apply(local, ops, mask, *, reply_width, interpret):
     P, L = local.shape
     m, w = ops.shape[1], ops.shape[2]
-    V = w - 6
-    G = reply_width - 1
-    kern = functools.partial(_fused_kernel, val_words=V, gather_words=G)
-    reply, new_local = pl.pallas_call(
+    kern = functools.partial(_fused_kernel, L=L, val_words=w - 6,
+                             gather_words=reply_width - 1)
+    tiles = to_tiles(local)
+    T = tiles.shape[1]
+    reply, new_tiles = pl.pallas_call(
         kern,
         grid=(P,),
-        in_specs=[
-            pl.BlockSpec((1, L), lambda i: (i, 0)),
-            pl.BlockSpec((1, m, w), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, m), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, m, reply_width), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, L), lambda i: (i, 0)),
-        ],
+        in_specs=[tile_spec(T), smem_spec(m * w), smem_spec(m)],
+        out_specs=[smem_spec(m * reply_width), tile_spec(T)],
         out_shape=[
-            jax.ShapeDtypeStruct((P, m, reply_width), jnp.int32),
-            jax.ShapeDtypeStruct((P, L), jnp.int32),
+            jax.ShapeDtypeStruct((P, 1, m * reply_width), jnp.int32),
+            jax.ShapeDtypeStruct(tiles.shape, jnp.int32),
         ],
         interpret=interpret,
-    )(local, ops, mask.astype(jnp.int32))
-    return reply, new_local
+    )(tiles, ops.astype(jnp.int32).reshape(P, 1, m * w),
+      mask.astype(jnp.int32).reshape(P, 1, m))
+    return reply.reshape(P, m, reply_width), from_tiles(new_tiles, L)

@@ -2,15 +2,17 @@
 
 Two kernels:
 
-- `hash_find`: embarrassingly parallel probe loop, vectorized over a tile
-  of `bm` requests; the local table stays resident in VMEM across the whole
-  request batch (one HBM read), each probe is a VMEM gather — exactly the
-  "expressive control flow at the target, zero extra network phases"
-  property the paper attributes to RPC handlers.
+- `hash_find`: the probe loop over a block of `block_m` requests; the
+  local table stays resident in VMEM across the owner's whole request
+  batch (one HBM read) — exactly the "expressive control flow at the
+  target, zero extra network phases" property the paper attributes to RPC
+  handlers.
 - `hash_insert`: sequential over requests within an owner (insert-or-assign
   must observe earlier inserts in the same batch — same serialization
-  argument as amo_apply), but each record read/write is a vectorized
-  rec_w-word VMEM slice.
+  argument as amo_apply).
+
+Each probe reads the record's flag and key words through their (8, 128)
+tiles (`tiles.py`); requests, replies and values are SMEM scalar streams.
 
 Layout: table (P, L) int32, nslots records of rec_w = 2 + vw words
 [flag | key | val...] per rank; flag low byte 0=EMPTY, 2=READY.
@@ -18,168 +20,175 @@ Layout: table (P, L) int32, nslots records of rec_w = 2 + vw words
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .tiles import (from_tiles, read_word, resolve_interpret, smem_spec,
+                    tile_spec, to_tiles, write_word)
+
+
+def _probe(table_ref, start, key, *, nslots, rec_w, max_probes,
+           want_empty):
+    """Walk one key's probe window. Returns (slot, kind, probes): kind 0
+    searching, 1 key found at slot, 2 empty slot (when `want_empty`;
+    otherwise an empty slot ends the search with kind 3)."""
+    def body(p, carry):
+        slot, kind, probes = carry
+        s = (start + p) % nslots
+        state = read_word(table_ref, s * rec_w) & 255
+        rkey = read_word(table_ref, s * rec_w + 1)
+        searching = kind == 0
+        hit = searching & (state == 2) & (rkey == key)
+        empty = searching & (state == 0)
+        slot = jnp.where(hit | (empty & want_empty), s, slot)
+        kind = jnp.where(hit, 1, jnp.where(empty, 2 if want_empty else 3,
+                                           kind))
+        return slot, kind, probes + searching.astype(jnp.int32)
+
+    return jax.lax.fori_loop(0, max_probes, body,
+                             (jnp.int32(-1), jnp.int32(0), jnp.int32(0)))
+
 
 def _find_kernel(table_ref, starts_ref, keys_ref, mask_ref,
                  found_ref, vals_ref, *, nslots, rec_w, max_probes):
-    # table (1, L); starts/keys/mask (1, bm); found (1, bm); vals (1, bm, vw)
-    table = table_ref[0]
-    starts = starts_ref[0]
-    keys = keys_ref[0]
-    bm = starts.shape[0]
+    # table (T, 8, 128) VMEM; starts/keys/mask/found (1, bm) SMEM;
+    # vals (1, bm * vw) SMEM
+    bm = starts_ref.shape[1]
     vw = rec_w - 2
 
-    def probe(j, carry):
-        found, vals, stop = carry
-        slot = (starts + j) % nslots
-        base = slot * rec_w
-        idx = base[:, None] + jnp.arange(rec_w)[None, :]   # (bm, rec_w)
-        rec = jnp.take(table, idx.reshape(-1), axis=0,
-                       mode="clip").reshape(bm, rec_w)
-        state = rec[:, 0] & 255
-        hit = (~stop) & (state == 2) & (rec[:, 1] == keys)
-        empty = (~stop) & (state == 0)
-        vals = jnp.where(hit[:, None], rec[:, 2:], vals)
-        return found | hit, vals, stop | hit | empty
+    def body(j, _):
+        slot, kind, _ = _probe(table_ref, starts_ref[0, j], keys_ref[0, j],
+                               nslots=nslots, rec_w=rec_w,
+                               max_probes=max_probes, want_empty=False)
+        hit = (kind == 1) & (mask_ref[0, j] != 0)
+        found_ref[0, j] = hit.astype(jnp.int32)
+        base = jnp.where(hit, slot * rec_w, 0)
+        for k in range(vw):
+            v = read_word(table_ref, base + 2 + k)
+            vals_ref[0, j * vw + k] = jnp.where(hit, v, 0)
+        return 0
 
-    found0 = jnp.zeros((bm,), jnp.bool_)
-    vals0 = jnp.zeros((bm, vw), jnp.int32)
-    found, vals, _ = jax.lax.fori_loop(0, max_probes, probe,
-                                       (found0, vals0, found0))
-    ok = mask_ref[0] != 0
-    found_ref[0] = (found & ok).astype(jnp.int32)
-    vals_ref[0] = jnp.where((found & ok)[:, None], vals, 0)
+    jax.lax.fori_loop(0, bm, body, 0)
+
+
+def hash_find(table: jax.Array, starts: jax.Array, keys: jax.Array,
+              mask: jax.Array, *, nslots: int, rec_w: int,
+              max_probes: int = 8, block_m: int = 128,
+              interpret: Optional[bool] = None):
+    """Batched find. table (P, L); starts/keys/mask (P, m).
+    Returns (found (P, m) bool, vals (P, m, rec_w-2)). On a TPU `block_m`
+    must be a multiple of 128 unless it covers the whole batch."""
+    return _hash_find(table, starts, keys, mask, nslots=nslots, rec_w=rec_w,
+                      max_probes=max_probes, block_m=block_m,
+                      interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("nslots", "rec_w", "max_probes",
                                              "block_m", "interpret"))
-def hash_find(table: jax.Array, starts: jax.Array, keys: jax.Array,
-              mask: jax.Array, *, nslots: int, rec_w: int,
-              max_probes: int = 8, block_m: int = 128,
-              interpret: bool = True):
-    """Vectorized batched find. table (P, L); starts/keys/mask (P, m).
-    Returns (found (P, m) bool, vals (P, m, rec_w-2))."""
-    P, L = table.shape
-    m = starts.shape[1]
+def _hash_find(table, starts, keys, mask, *, nslots, rec_w, max_probes,
+               block_m, interpret):
+    P, m = starts.shape
     bm = min(block_m, m)
     grid_m = pl.cdiv(m, bm)
+    mp = grid_m * bm
     vw = rec_w - 2
+    tiles = to_tiles(table)
     kern = functools.partial(_find_kernel, nslots=nslots, rec_w=rec_w,
                              max_probes=max_probes)
     found, vals = pl.pallas_call(
         kern,
         grid=(P, grid_m),
-        in_specs=[
-            pl.BlockSpec((1, L), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, bm), lambda i, j: (i, j)),
-            pl.BlockSpec((1, bm), lambda i, j: (i, j)),
-            pl.BlockSpec((1, bm), lambda i, j: (i, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bm), lambda i, j: (i, j)),
-            pl.BlockSpec((1, bm, vw), lambda i, j: (i, j, 0)),
-        ],
+        in_specs=[tile_spec(tiles.shape[1])]
+        + [smem_spec(bm, blocked=True)] * 3,
+        out_specs=[smem_spec(bm, blocked=True),
+                   smem_spec(bm * vw, blocked=True)],
         out_shape=[
-            jax.ShapeDtypeStruct((P, grid_m * bm), jnp.int32),
-            jax.ShapeDtypeStruct((P, grid_m * bm, vw), jnp.int32),
+            jax.ShapeDtypeStruct((P, 1, mp), jnp.int32),
+            jax.ShapeDtypeStruct((P, 1, mp * vw), jnp.int32),
         ],
         interpret=interpret,
-    )(table, _pad(starts, grid_m * bm), _pad(keys, grid_m * bm),
-      _pad(mask.astype(jnp.int32), grid_m * bm))
-    return found[:, :m] != 0, vals[:, :m]
+    )(tiles, *(_stream(x, mp) for x in (starts, keys, mask)))
+    return (found.reshape(P, mp)[:, :m] != 0,
+            vals.reshape(P, mp, vw)[:, :m])
 
 
-def _pad(x: jax.Array, to: int) -> jax.Array:
-    if x.shape[1] == to:
-        return x
-    pad = [(0, 0), (0, to - x.shape[1])] + [(0, 0)] * (x.ndim - 2)
-    return jnp.pad(x, pad)
+def _stream(x: jax.Array, to: int) -> jax.Array:
+    """(P, m) -> (P, 1, to) int32 SMEM stream, zero-padded."""
+    x = x.astype(jnp.int32)
+    if x.shape[1] != to:
+        x = jnp.pad(x, ((0, 0), (0, to - x.shape[1])))
+    return x[:, None, :]
 
 
 def _insert_kernel(table_ref, starts_ref, keys_ref, vals_ref, mask_ref,
                    ok_ref, probes_ref, out_ref, *, nslots, rec_w,
                    max_probes):
-    # sequential insert-or-assign over the owner's request list. All
-    # pl.load/pl.store indices are pl.ds slices (bare scalar ints break
-    # interpret-mode state discharge).
+    # sequential insert-or-assign over the owner's request list
     out_ref[...] = table_ref[...]
     m = starts_ref.shape[1]
     vw = rec_w - 2
 
     def body(j, _):
-        start = starts_ref[0, j]
         key = keys_ref[0, j]
         ok = mask_ref[0, j] != 0
-
-        def probe(p, carry):
-            slot, kind, probes = carry  # kind: 0 searching, 1 hit, 2 empty
-            s = (start + p) % nslots
-            rec = pl.load(out_ref, (pl.ds(0, 1), pl.ds(s * rec_w, 2)))[0]
-            state = rec[0] & 255
-            searching = kind == 0
-            hit = searching & (state == 2) & (rec[1] == key)
-            empty = searching & (state == 0)
-            slot = jnp.where(hit | empty, s, slot)
-            kind = jnp.where(hit, 1, jnp.where(empty, 2, kind))
-            probes = probes + searching.astype(jnp.int32)
-            return slot, kind, probes
-
-        slot, kind, probes = jax.lax.fori_loop(
-            0, max_probes, probe, (jnp.int32(-1), jnp.int32(0),
-                                   jnp.int32(0)))
+        slot, kind, probes = _probe(out_ref, starts_ref[0, j], key,
+                                    nslots=nslots, rec_w=rec_w,
+                                    max_probes=max_probes, want_empty=True)
         can = ok & (kind > 0)
-        base = jnp.where(can, slot * rec_w, 0)
-        cur = pl.load(out_ref, (pl.ds(0, 1), pl.ds(base, rec_w)))[0]
-        val = pl.load(vals_ref, (pl.ds(0, 1), pl.ds(j, 1),
-                                 pl.ds(0, vw)))[0, 0]
-        rec = jnp.concatenate([jnp.full((1,), 2, jnp.int32), key[None], val])
-        pl.store(out_ref, (pl.ds(0, 1), pl.ds(base, rec_w)),
-                 jnp.where(can, rec, cur)[None])
-        pl.store(ok_ref, (pl.ds(0, 1), pl.ds(j, 1)),
-                 can.astype(jnp.int32)[None, None])
-        pl.store(probes_ref, (pl.ds(0, 1), pl.ds(j, 1)),
-                 jnp.where(ok, probes, 0)[None, None])
+
+        @pl.when(can)
+        def _():
+            base = slot * rec_w
+            write_word(out_ref, base, jnp.int32(2))
+            write_word(out_ref, base + 1, key)
+            for k in range(vw):
+                write_word(out_ref, base + 2 + k, vals_ref[0, j * vw + k])
+
+        ok_ref[0, j] = can.astype(jnp.int32)
+        probes_ref[0, j] = jnp.where(ok, probes, 0)
         return 0
 
     jax.lax.fori_loop(0, m, body, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("nslots", "rec_w", "max_probes",
-                                             "interpret"))
 def hash_insert(table: jax.Array, starts: jax.Array, keys: jax.Array,
                 vals: jax.Array, mask: jax.Array, *, nslots: int,
-                rec_w: int, max_probes: int = 8, interpret: bool = True):
+                rec_w: int, max_probes: int = 8,
+                interpret: Optional[bool] = None):
     """Serialized batched insert-or-assign. vals (P, m, rec_w-2).
     Returns (ok (P, m) bool, probes (P, m) int32, table')."""
+    return _hash_insert(table, starts, keys, vals, mask, nslots=nslots,
+                        rec_w=rec_w, max_probes=max_probes,
+                        interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("nslots", "rec_w", "max_probes",
+                                             "interpret"))
+def _hash_insert(table, starts, keys, vals, mask, *, nslots, rec_w,
+                 max_probes, interpret):
     P, L = table.shape
     m = starts.shape[1]
     vw = rec_w - 2
+    tiles = to_tiles(table)
+    T = tiles.shape[1]
     kern = functools.partial(_insert_kernel, nslots=nslots, rec_w=rec_w,
                              max_probes=max_probes)
-    ok, probes, new_table = pl.pallas_call(
+    ok, probes, new_tiles = pl.pallas_call(
         kern,
         grid=(P,),
-        in_specs=[
-            pl.BlockSpec((1, L), lambda i: (i, 0)),
-            pl.BlockSpec((1, m), lambda i: (i, 0)),
-            pl.BlockSpec((1, m), lambda i: (i, 0)),
-            pl.BlockSpec((1, m, vw), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, m), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, m), lambda i: (i, 0)),
-            pl.BlockSpec((1, m), lambda i: (i, 0)),
-            pl.BlockSpec((1, L), lambda i: (i, 0)),
-        ],
+        in_specs=[tile_spec(T), smem_spec(m), smem_spec(m),
+                  smem_spec(m * vw), smem_spec(m)],
+        out_specs=[smem_spec(m), smem_spec(m), tile_spec(T)],
         out_shape=[
-            jax.ShapeDtypeStruct((P, m), jnp.int32),
-            jax.ShapeDtypeStruct((P, m), jnp.int32),
-            jax.ShapeDtypeStruct((P, L), jnp.int32),
+            jax.ShapeDtypeStruct((P, 1, m), jnp.int32),
+            jax.ShapeDtypeStruct((P, 1, m), jnp.int32),
+            jax.ShapeDtypeStruct(tiles.shape, jnp.int32),
         ],
         interpret=interpret,
-    )(table, starts, keys, vals, mask.astype(jnp.int32))
-    return ok != 0, probes, new_table
+    )(tiles, _stream(starts, m), _stream(keys, m),
+      vals.astype(jnp.int32).reshape(P, 1, m * vw), _stream(mask, m))
+    return (ok.reshape(P, m) != 0, probes.reshape(P, m),
+            from_tiles(new_tiles, L))

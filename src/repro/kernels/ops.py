@@ -1,8 +1,8 @@
 """ops — the jit'd public entry points for the kernel layer.
 
-Every op dispatches between the Pallas kernel (TPU hot path; validated on
-CPU via interpret=True) and the pure-jnp reference (`ref.py`), controlled
-by `use_pallas`. The model/launch layers call these; the dry-run compiles
+Every op dispatches between the Pallas kernel (compiled on a TPU,
+interpreted on the CPU backend) and the pure-jnp reference (`ref.py`),
+controlled by `use_pallas`. The model/launch layers call these; the dry-run compiles
 the jnp path (Pallas does not lower on the CPU backend), which is
 numerically identical — the kernels are the *performance* realization.
 """
@@ -24,7 +24,7 @@ from . import rg_lru as _rg
 
 Array = jax.Array
 
-# Default backend: Pallas-in-interpret on CPU iff explicitly requested.
+# The Pallas lane only when explicitly requested (ROADMAP S6/D3 decide it).
 _USE_PALLAS = os.environ.get("REPRO_USE_PALLAS", "0") == "1"
 
 

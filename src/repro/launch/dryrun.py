@@ -121,9 +121,6 @@ def dryrun_cell(arch: str, shape_name: str, mesh_kind: str,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
     cost = compiled.cost_analysis() or {}
-    # jax 0.4.x returns a one-element list of dicts; >=0.5 a plain dict.
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     try:
         mem = compiled.memory_analysis()
         mem_info = {

@@ -20,19 +20,11 @@ ICI_BW_PER_LINK = 50e9          # bytes/s/link (~4 links usable per chip)
 ICI_LINKS = 4
 
 
-def _axis_types_kwargs(n_axes: int) -> dict:
-    """jax >= 0.5 wants explicit axis_types; jax 0.4.x (this image ships
-    0.4.37) has no jax.sharding.AxisType and every axis is Auto by
-    default — pass nothing there."""
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
@@ -40,5 +32,5 @@ def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, max(n // data, 1))
-    return jax.make_mesh(
-        (data, model), ("data", "model"), **_axis_types_kwargs(2))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -67,12 +67,7 @@ def mesh_context(mesh: Optional[Mesh], rules: Optional[dict] = None):
         ctx["rules"] = {**DEFAULT_RULES, **rules}
     try:
         if mesh is not None:
-            # jax >= 0.5: jax.sharding.set_mesh / use_mesh. jax 0.4.x has
-            # neither; there the Mesh object itself is the context manager
-            # that makes bare-PartitionSpec with_sharding_constraint work.
-            enter = (getattr(jax.sharding, "set_mesh", None)
-                     or getattr(jax.sharding, "use_mesh", None))
-            with (enter(mesh) if enter is not None else mesh):
+            with jax.sharding.set_mesh(mesh):
                 yield
         else:
             yield

@@ -1,5 +1,5 @@
-"""Per-kernel validation: Pallas (interpret=True) vs the pure-jnp oracles
-in kernels/ref.py, swept over shapes and dtypes."""
+"""Per-kernel validation: Pallas (interpreted on the CPU) vs the pure-jnp
+oracles in kernels/ref.py, swept over shapes and dtypes."""
 import jax
 import jax.numpy as jnp
 import numpy as np

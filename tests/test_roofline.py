@@ -10,11 +10,7 @@ from repro.launch import hlo_stats
 
 
 def _cost(compiled) -> dict:
-    """jax 0.4.x cost_analysis() returns a one-element list of dicts."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    return compiled.cost_analysis() or {}
 
 
 def test_xla_cost_analysis_counts_loop_body_once():
@@ -87,12 +83,9 @@ ENTRY %main (p: f32[64,128]) -> f32[64,128] {
 def test_hlo_stats_sharded_collectives_end_to_end():
     """all_to_all via shard_map on 1 device degenerates; instead check a
     psum-lowered all-reduce is found and byte-counted."""
-    from repro.launch.mesh import _axis_types_kwargs
-    mesh = jax.make_mesh((1,), ("d",), **_axis_types_kwargs(1))
-    if hasattr(jax, "shard_map"):
-        shard_map = jax.shard_map
-    else:  # jax 0.4.x
-        from jax.experimental.shard_map import shard_map
+    mesh = jax.make_mesh((1,), ("d",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    shard_map = jax.shard_map
 
     def f(x):
         return shard_map(lambda a: jax.lax.psum(a, "d"),
