@@ -31,10 +31,9 @@ online signals the engine maintains itself:
     (DESIGN.md §6) — hot-key batches collapse toward O(distinct) wire
     rows, tilting the model back toward the coalesced one-sided arms.
 
-Every choice is recorded as a `Decision`; the RDMA arms run inside
-`window.decision_scope` and the AM arms thread the record into
-`AMEngine.dispatch`, so benchmarks can attribute every network phase to the
-arm that issued it.
+Every choice is recorded as a `Decision` in `AdaptiveEngine.log`; the RDMA
+arms run inside `window.decision_scope`, so their phases are attributed
+to the arm that issued them.
 
 Under `jax.jit` tracing the destinations are abstract: the skew falls back
 to 1.0, timing is skipped, and the decision degrades to the pure
@@ -53,14 +52,15 @@ import numpy as np
 
 from . import costmodel as cm
 from . import faults as flt
+from . import trace
 from .costmodel import ARMS, ComponentCosts, DSOp
-from .types import OpStats, Promise, host_value
+from .types import OpStats, Promise, device_wait, host_value
 
 
 @dataclass(frozen=True)
 class Decision:
-    """One per-batch backend choice — the record shared with
-    `AMEngine.dispatch` and `window.decision_scope` call sites."""
+    """One per-batch backend choice — the record kept in
+    `AdaptiveEngine.log` and tagged on phases by `window.decision_scope`."""
 
     op: DSOp
     promise: Promise
@@ -643,7 +643,7 @@ class AdaptiveEngine:
         if any(isinstance(x, jax.core.Tracer)
                for x in jax.tree_util.tree_leaves(out)):
             return out  # traced values: skip the observation
-        jax.block_until_ready(out)
+        device_wait(out)
         us = (time.perf_counter() - t0) * 1e6 / dec.batch_ops
         if dec.arm == "am_pt":
             us *= self.params.pt_overhead
@@ -689,8 +689,9 @@ class AdaptiveEngine:
         from . import hashtable as ht_mod
         from . import window as win_mod
         dst, _ = ht_mod._place(ht, keys)
-        dec = self.decide(DSOp.HT_INSERT, promise, dst, valid,
-                          self._ht_stats(keys, valid, stats))
+        with trace.span("chooser.decide"):
+            dec = self.decide(DSOp.HT_INSERT, promise, dst, valid,
+                              self._ht_stats(keys, valid, stats))
         self._observe_rw(is_write=True)
         if self.cache is not None:
             # authoritative invalidation: versions bump before any write
@@ -702,8 +703,7 @@ class AdaptiveEngine:
                 lambda e: ht_mod.build_am_handlers(ht, e,
                                                    max_probes=max_probes))
             ht2, ok, probes = self._timed(dec, lambda: ht_mod.insert_rpc(
-                ht, eng, keys, vals, valid=valid, decision=dec,
-                coalesce=dec.coalesce))
+                ht, eng, keys, vals, valid=valid, coalesce=dec.coalesce))
             uns = self._after_am()
             if uns is not None:
                 # §10 failover: rows whose owner never serviced the AM
@@ -757,11 +757,12 @@ class AdaptiveEngine:
         from . import hashtable as ht_mod
         from . import window as win_mod
         dst, _ = ht_mod._place(ht, keys)
-        s = self._ht_stats(keys, valid, stats)
-        reads_cached = (self.cache_reads_on() and promise == Promise.CR)
-        if reads_cached and s.hit_rate == 0.0:
-            s = replace(s, hit_rate=self.hit_ewma)
-        dec = self.decide(DSOp.HT_FIND, promise, dst, valid, s)
+        with trace.span("chooser.decide"):
+            s = self._ht_stats(keys, valid, stats)
+            reads_cached = (self.cache_reads_on() and promise == Promise.CR)
+            if reads_cached and s.hit_rate == 0.0:
+                s = replace(s, hit_rate=self.hit_ewma)
+            dec = self.decide(DSOp.HT_FIND, promise, dst, valid, s)
         self._observe_rw(is_write=False)
         if dec.arm in ("am", "am_pt"):
             eng = self._need_am(
@@ -769,8 +770,7 @@ class AdaptiveEngine:
                 lambda e: ht_mod.build_am_handlers(ht, e,
                                                    max_probes=max_probes))
             found, vals = self._timed(dec, lambda: ht_mod.find_rpc(
-                ht, eng, keys, valid=valid, decision=dec,
-                coalesce=dec.coalesce))
+                ht, eng, keys, valid=valid, coalesce=dec.coalesce))
             uns = self._after_am()
             if uns is not None:
                 # §10 failover: unserviced finds re-read one-sided (reply
@@ -809,15 +809,16 @@ class AdaptiveEngine:
         from . import queue as q_mod
         from . import window as win_mod
         P, n, _ = vals.shape
-        dec = self.decide(DSOp.Q_PUSH, promise, valid=valid,
-                          stats=self._host_stats(stats),
-                          nops=P * n if valid is None else None,
-                          owners=(q.host,))
+        with trace.span("chooser.decide"):
+            dec = self.decide(DSOp.Q_PUSH, promise, valid=valid,
+                              stats=self._host_stats(stats),
+                              nops=P * n if valid is None else None,
+                              owners=(q.host,))
         if dec.arm in ("am", "am_pt"):
             eng = self._need_am(
                 "q_push", lambda e: q_mod.build_am_handlers(q, e))
             q2, ok = self._timed(dec, lambda: q_mod.push_rpc(
-                q, eng, vals, valid=valid, decision=dec))
+                q, eng, vals, valid=valid))
             uns = self._after_am()
             if uns is not None:
                 # §10 failover: the queue is hosted on ONE rank, so a dead
@@ -850,15 +851,16 @@ class AdaptiveEngine:
         """Adaptive queue pop: returns (queue', got, vals)."""
         from . import queue as q_mod
         from . import window as win_mod
-        dec = self.decide(DSOp.Q_POP, promise, valid=valid,
-                          stats=self._host_stats(stats),
-                          nops=q.nranks * n if valid is None else None,
-                          owners=(q.host,))
+        with trace.span("chooser.decide"):
+            dec = self.decide(DSOp.Q_POP, promise, valid=valid,
+                              stats=self._host_stats(stats),
+                              nops=q.nranks * n if valid is None else None,
+                              owners=(q.host,))
         if dec.arm in ("am", "am_pt"):
             eng = self._need_am(
                 "q_pop", lambda e: q_mod.build_am_handlers(q, e))
             q2, got, pvals = self._timed(dec, lambda: q_mod.pop_rpc(
-                q, eng, n, valid=valid, decision=dec))
+                q, eng, n, valid=valid))
             uns = self._after_am()
             if uns is not None:
                 # §10 failover: unserviced pops never consumed anything —

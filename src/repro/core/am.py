@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from . import faults as flt
 from . import routing
+from . import trace
 
 Array = jax.Array
 
@@ -48,22 +49,12 @@ class Handler:
                                   Tuple[Any, Array]]] = None
 
 
-# Explicit bound on the dispatch diagnostic ring (see AMEngine.dispatch_log).
-DISPATCH_LOG_MAX = 1024
-
-
 class AMEngine:
     """Handler registry + dispatch. One engine per distributed structure."""
 
-    def __init__(self, nranks: int, dispatch_log_max: int = DISPATCH_LOG_MAX):
+    def __init__(self, nranks: int):
         self.nranks = nranks
         self._handlers: dict[str, Handler] = {}
-        # (handler name, Decision, info) per dispatch issued by the adaptive
-        # layer — benchmarks read this to log which arm serviced a batch.
-        # Bounded ring: library callers never drain it; `drain_dispatch_log`
-        # returns-and-clears for callers that do.
-        self.dispatch_log: collections.deque = collections.deque(
-            maxlen=dispatch_log_max)
         # Deferred-dispatch queue (DESIGN.md §7): AM batches submitted
         # through the pipeline engine wait here until the next *dispatch
         # point* — the paper's attentiveness, made an explicit queue. The
@@ -75,12 +66,6 @@ class AMEngine:
         # with the inter-submit busy_wait knob this makes attentiveness a
         # measurable quantity (benchmarks/pipeline_bench.py).
         self.dispatch_points = 0
-
-    def drain_dispatch_log(self):
-        """Return and clear the (handler, decision, info) dispatch log."""
-        out = list(self.dispatch_log)
-        self.dispatch_log.clear()
-        return out
 
     # -- deferred dispatch (pipeline integration, DESIGN.md §7) ------------
     @property
@@ -146,7 +131,6 @@ class AMEngine:
                  payload: Array, valid: Optional[Array] = None,
                  cap: Optional[int] = None,
                  plan: Optional[routing.RoutePlan] = None,
-                 decision: Optional[Any] = None,
                  coalesce: bool = False
                  ) -> Tuple[Any, Array, Array]:
         """Issue one aggregated AM phase for a batch of requests.
@@ -172,57 +156,61 @@ class AMEngine:
         reply_width == 0 a single phase (the origin-side completion counter
         is derivable locally from `delivered`, matching the paper's
         counter-increment reply elision).
-        """
-        plane = flt.active_plane()
-        if plane is not None:
-            # DESIGN.md §10, applied pre-coalescing at op-row granularity:
-            # rows to a dead/stalled owner are masked undelivered (and
-            # recorded for the adaptive layer's one-sided failover); live
-            # rows go through wire-loss retransmit + dedup simulation.
-            valid = plane.inject_am(dst, valid)
-            plane.tick()
-        co = None
-        eff_valid = valid
-        if coalesce:
-            co = routing.coalesce(dst, payload[..., 0], match=payload,
-                                  valid=valid)
-            eff_valid = co.rep if valid is None else (valid & co.rep)
-        if decision is not None:
-            info = None
-            if co is not None:
-                from . import window as win_mod
-                info = win_mod._coalesce_info(co)
-            self.dispatch_log.append((handler.name, decision, info))
-        if plan is not None:
-            cap = plan.cap
-            routed = routing.route_with_plan(plan, payload,
-                                             active=eff_valid,
-                                             role="am_req")
-        else:
-            cap = dst.shape[1] if cap is None else cap
-            routed = routing.route(dst, payload, cap, eff_valid,
-                                   role="am_req")
-        flat, mask = routing.flatten_owner_view(routed)
 
-        if handler.batched_fn is not None:
-            state2, reply_flat = handler.batched_fn(state, flat, mask)
-        else:
-            state2, reply_flat = jax.vmap(handler.fn)(state, flat, mask)
-        delivered = routed.op_ok
-        if co is not None:
-            # reply fan-out: duplicates are delivered iff their
-            # representative was
-            delivered = routing.lead(co, delivered)
-            if valid is not None:
-                delivered = delivered & valid
-        if handler.reply_width == 0:
-            replies = jnp.zeros(dst.shape + (0,), dtype=jnp.int32)
+        Traced as the spans `ds.am.request` (routing), `ds.owner_apply`
+        (the handler, also a named scope on its device ops) and
+        `ds.am.reply`.
+        """
+        with trace.span("am.request"):
+            plane = flt.active_plane()
+            if plane is not None:
+                # DESIGN.md §10, applied pre-coalescing at op-row
+                # granularity: rows to a dead/stalled owner are masked
+                # undelivered (and recorded for the adaptive layer's
+                # one-sided failover); live rows go through wire-loss
+                # retransmit + dedup simulation.
+                valid = plane.inject_am(dst, valid)
+                plane.tick()
+            co = None
+            eff_valid = valid
+            if coalesce:
+                co = routing.coalesce(dst, payload[..., 0], match=payload,
+                                      valid=valid)
+                eff_valid = co.rep if valid is None else (valid & co.rep)
+            if plan is not None:
+                cap = plan.cap
+                routed = routing.route_with_plan(plan, payload,
+                                                 active=eff_valid,
+                                                 role="am_req")
+            else:
+                cap = dst.shape[1] if cap is None else cap
+                routed = routing.route(dst, payload, cap, eff_valid,
+                                       role="am_req")
+            flat, mask = routing.flatten_owner_view(routed)
+
+        with trace.span("owner_apply"), jax.named_scope("ds.owner_apply"):
+            if handler.batched_fn is not None:
+                state2, reply_flat = handler.batched_fn(state, flat, mask)
+            else:
+                state2, reply_flat = jax.vmap(handler.fn)(state, flat, mask)
+        with trace.span("am.reply"):
+            delivered = routed.op_ok
+            if co is not None:
+                # reply fan-out: duplicates are delivered iff their
+                # representative was
+                delivered = routing.lead(co, delivered)
+                if valid is not None:
+                    delivered = delivered & valid
+            if handler.reply_width == 0:
+                replies = jnp.zeros(dst.shape + (0,), dtype=jnp.int32)
+                return state2, replies, delivered
+            replies_o = routing.unflatten_owner_view(reply_flat, self.nranks,
+                                                     cap)
+            replies = routing.route_replies(routed, replies_o, dst,
+                                            role="am_rep")
+            if co is not None:
+                replies = routing.lead(co, replies)
             return state2, replies, delivered
-        replies_o = routing.unflatten_owner_view(reply_flat, self.nranks, cap)
-        replies = routing.route_replies(routed, replies_o, dst, role="am_rep")
-        if co is not None:
-            replies = routing.lead(co, replies)
-        return state2, replies, delivered
 
     def dispatch_local(self, handler: Handler, state: Any, payload: Array,
                        valid: Optional[Array] = None

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from . import am as am_mod
 from . import routing
+from . import trace
 from . import window as win_mod
 from .types import (FLAG_EMPTY, FLAG_READY, FLAG_RESERVED, READ_UNIT,
                     STATE_MASK, Backend, Promise, as_backend)
@@ -188,9 +189,14 @@ def insert_rdma(ht: DHashTable, keys: Array, vals: Array,
         # seed path keeps its fixed trip count.
         claimed0 = jnp.full(keys.shape, -1, dtype=jnp.int32)
         probes0 = jnp.zeros(keys.shape, dtype=jnp.int32)
-        _, win, active, claimed, probes = jax.lax.while_loop(
-            lambda c: (c[0] < max_probes) & c[2].any(), probe_fused,
-            (jnp.int32(0), ht.win, valid, claimed0, probes0))
+        loop = ("insert_rdma.fused", promise, coalesce)
+        with trace.span("probe_loop"):
+            trips, win, active, claimed, probes = jax.lax.while_loop(
+                lambda c: (c[0] < max_probes) & c[2].any(),
+                trace.per_trip(loop, probe_fused),
+                (jnp.int32(0), ht.win, valid, claimed0, probes0))
+        trace.count("probe_trips", trips)
+        trace.trips(loop, trips)
         success = valid & ~active
         return (DHashTable(win=win, nslots=nslots, val_words=ht.val_words),
                 success, probes)
@@ -210,8 +216,11 @@ def insert_rdma(ht: DHashTable, keys: Array, vals: Array,
 
     claimed0 = jnp.full(keys.shape, -1, dtype=jnp.int32)
     probes0 = jnp.zeros(keys.shape, dtype=jnp.int32)
+    loop = ("insert_rdma", promise, coalesce)
     win, active, claimed, probes = jax.lax.fori_loop(
-        0, max_probes, probe_phase, (ht.win, valid, claimed0, probes0))
+        0, max_probes, trace.per_trip(loop, probe_phase),
+        (ht.win, valid, claimed0, probes0))
+    trace.trips(loop, max_probes)
     success = valid & ~active
 
     # ONE put phase writes [key | val words] for every claimed op.
@@ -370,8 +379,13 @@ def find_rdma(ht: DHashTable, keys: Array,
         carry0 = (jnp.int32(0), ht.win, eff_valid, found0, out0)
         if track:
             carry0 = carry0 + (jnp.full(keys.shape, -1, jnp.int32),)
-        fin = jax.lax.while_loop(
-            lambda c: (c[0] < max_probes) & c[2].any(), probe_fused, carry0)
+        loop = ("find_rdma.fused", promise, coalesce, track)
+        with trace.span("probe_loop"):
+            fin = jax.lax.while_loop(
+                lambda c: (c[0] < max_probes) & c[2].any(),
+                trace.per_trip(loop, probe_fused), carry0)
+        trace.count("probe_trips", fin[0])
+        trace.trips(loop, fin[0])
         win, found, out = fin[1], fin[3], fin[4]
         if look is not None:
             hitm = jnp.asarray(look.hit)
@@ -385,9 +399,12 @@ def find_rdma(ht: DHashTable, keys: Array,
             return (DHashTable(win=win, nslots=nslots,
                                val_words=ht.val_words), found, out, fin[5])
     else:
+        loop = ("find_rdma", promise, coalesce)
         win, _, found, out = jax.lax.fori_loop(
             0, max_probes,
-            lambda j, c: probe_body(j, *c), (ht.win, valid, found0, out0))
+            trace.per_trip(loop, lambda j, c: probe_body(j, *c)),
+            (ht.win, valid, found0, out0))
+        trace.trips(loop, max_probes)
     return (DHashTable(win=win, nslots=nslots, val_words=ht.val_words),
             found, out)
 
@@ -610,7 +627,7 @@ def build_am_handlers(ht: DHashTable, engine: am_mod.AMEngine,
 
 def insert_rpc(ht: DHashTable, engine: am_mod.AMEngine, keys: Array,
                vals: Array, valid: Optional[Array] = None,
-               decision=None, coalesce: bool = False
+               coalesce: bool = False
                ) -> Tuple[DHashTable, Array, Array]:
     """Insert-or-assign via ONE AM round trip (cost: am_rt + handler).
 
@@ -624,8 +641,7 @@ def insert_rpc(ht: DHashTable, engine: am_mod.AMEngine, keys: Array,
                               axis=-1)
     h = engine.handler("ht_insert")
     data, replies, delivered = engine.dispatch(h, ht.win.data, dst, payload,
-                                               valid, decision=decision,
-                                               coalesce=coalesce)
+                                               valid, coalesce=coalesce)
     ok = delivered & (replies[..., 0] > 0)
     probes = jnp.where(delivered, replies[..., 1], 0)
     return (DHashTable(win=Window(data=data), nslots=ht.nslots,
@@ -633,14 +649,13 @@ def insert_rpc(ht: DHashTable, engine: am_mod.AMEngine, keys: Array,
 
 
 def find_rpc(ht: DHashTable, engine: am_mod.AMEngine, keys: Array,
-             valid: Optional[Array] = None, decision=None,
+             valid: Optional[Array] = None,
              coalesce: bool = False) -> Tuple[Array, Array]:
     dst, start = _place(ht, keys)
     payload = jnp.concatenate([start[..., None], keys[..., None]], axis=-1)
     h = engine.handler("ht_find")
     _, replies, delivered = engine.dispatch(h, ht.win.data, dst, payload,
-                                            valid, decision=decision,
-                                            coalesce=coalesce)
+                                            valid, coalesce=coalesce)
     found = delivered & (replies[..., 0] > 0)
     return found, replies[..., 1:]
 
@@ -673,15 +688,16 @@ def insert(ht, keys, vals, *, promise=Promise.CRW, backend=Backend.AUTO,
     are bit-identical across every backend on the conformance domain
     (tests/test_conformance.py); tracer-safe — under jit the AUTO choice
     degrades to the static model decision (DESIGN.md §4)."""
-    backend = as_backend(backend)
-    if backend == Backend.AUTO:
-        from . import adaptive as ad
-        a = adaptive or ad.default_engine(ht.nranks, am_engine=engine)
-        return a.ht_insert(ht, keys, vals, promise=promise, **kw)
-    if backend == Backend.RPC:
-        return insert_rpc(ht, engine, keys, vals, valid=kw.get("valid"),
-                          coalesce=kw.get("coalesce", False))
-    return insert_rdma(ht, keys, vals, promise=promise, **kw)
+    with trace.root("insert"):
+        backend = as_backend(backend)
+        if backend == Backend.AUTO:
+            from . import adaptive as ad
+            a = adaptive or ad.default_engine(ht.nranks, am_engine=engine)
+            return a.ht_insert(ht, keys, vals, promise=promise, **kw)
+        if backend == Backend.RPC:
+            return insert_rpc(ht, engine, keys, vals, valid=kw.get("valid"),
+                              coalesce=kw.get("coalesce", False))
+        return insert_rdma(ht, keys, vals, promise=promise, **kw)
 
 
 def find(ht, keys, *, promise=Promise.CR, backend=Backend.AUTO, engine=None,
@@ -693,16 +709,17 @@ def find(ht, keys, *, promise=Promise.CR, backend=Backend.AUTO, engine=None,
     vals are zeros where not found. The table is returned because a C_RW
     find mutates reader counts; for CR it is unchanged. Bit-identical
     visible results across backends (tests/test_conformance.py)."""
-    backend = as_backend(backend)
-    if backend == Backend.AUTO:
-        from . import adaptive as ad
-        a = adaptive or ad.default_engine(ht.nranks, am_engine=engine)
-        return a.ht_find(ht, keys, promise=promise, **kw)
-    if backend == Backend.RPC:
-        found, vals = find_rpc(ht, engine, keys, valid=kw.get("valid"),
-                               coalesce=kw.get("coalesce", False))
-        return ht, found, vals
-    return find_rdma(ht, keys, promise=promise, **kw)
+    with trace.root("find"):
+        backend = as_backend(backend)
+        if backend == Backend.AUTO:
+            from . import adaptive as ad
+            a = adaptive or ad.default_engine(ht.nranks, am_engine=engine)
+            return a.ht_find(ht, keys, promise=promise, **kw)
+        if backend == Backend.RPC:
+            found, vals = find_rpc(ht, engine, keys, valid=kw.get("valid"),
+                                   coalesce=kw.get("coalesce", False))
+            return ht, found, vals
+        return find_rdma(ht, keys, promise=promise, **kw)
 
 
 # ---------------------------------------------------------------------------

@@ -59,6 +59,7 @@ import jax
 
 from . import faults as flt
 from . import window as win_mod
+from .types import device_wait
 
 # An op stages one batch against the current structure state and returns
 # (state', outputs). Outputs are what Handle.result() yields.
@@ -300,7 +301,7 @@ class Pipeline:
         self._drain_deferred()
         while self._inflight:
             self._force(self._inflight[0])
-        jax.block_until_ready(jax.tree_util.tree_leaves(self._state))
+        device_wait(jax.tree_util.tree_leaves(self._state))
         return self._state
 
     # -- internals ----------------------------------------------------------
@@ -373,7 +374,7 @@ class Pipeline:
                     self._note_inflight()
                     raise err
         assert h._staged, "deferred batch did not stage at dispatch point"
-        jax.block_until_ready(jax.tree_util.tree_leaves(h._outputs))
+        device_wait(jax.tree_util.tree_leaves(h._outputs))
         h._forced = True
         try:
             self._inflight.remove(h)

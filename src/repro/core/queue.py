@@ -40,6 +40,7 @@ import jax.numpy as jnp
 
 from . import am as am_mod
 from . import routing
+from . import trace
 from . import window as win_mod
 from .types import AmoKind, Backend, Promise, as_backend
 from .window import Window, rdma_cas, rdma_fao, rdma_get, rdma_put
@@ -179,8 +180,11 @@ def push_rdma(q: DQueue, vals: Array, promise: Promise = Promise.CRW,
             done = pending & (old == ticket)
             return win, pending & ~done
 
-        win, pending = jax.lax.fori_loop(0, max_cas_rounds, round_,
+        loop = ("push_rdma.publish", planned)
+        win, pending = jax.lax.fori_loop(0, max_cas_rounds,
+                                         trace.per_trip(loop, round_),
                                          (win, pending))
+        trace.trips(loop, max_cas_rounds)
         ok = ok & ~pending  # unpublished pushes report failure
     return (DQueue(win=win, host=q.host, capacity=q.capacity,
                    val_words=q.val_words, checksum=q.checksum), ok)
@@ -252,8 +256,11 @@ def pop_rdma(q: DQueue, n: int, promise: Promise = Promise.CR,
             done = pending & (old == ticket)
             return win, pending & ~done
 
-        win, _ = jax.lax.fori_loop(0, max_cas_rounds, round_,
+        loop = ("pop_rdma.release", planned)
+        win, _ = jax.lax.fori_loop(0, max_cas_rounds,
+                                   trace.per_trip(loop, round_),
                                    (win, pending))
+        trace.trips(loop, max_cas_rounds)
     # Failed pops report zeros, not routing garbage: the reply words of
     # undelivered ops are garbage by contract in the unplanned engine, and
     # the adaptive layer swaps backends per batch — visible results must be
@@ -503,28 +510,26 @@ def build_am_handlers(q: DQueue, engine: am_mod.AMEngine):
 
 
 def push_rpc(q: DQueue, engine: am_mod.AMEngine, vals: Array,
-             valid: Optional[Array] = None,
-             decision=None) -> Tuple[DQueue, Array]:
+             valid: Optional[Array] = None) -> Tuple[DQueue, Array]:
     """Push via ONE AM round trip."""
     P, n, _ = vals.shape
     dst = _host_dst(q, (P, n))
     h = engine.handler("q_push")
     data, replies, delivered = engine.dispatch(h, q.win.data, dst, vals,
-                                               valid, decision=decision)
+                                               valid)
     ok = delivered & (replies[..., 0] > 0)
     return (DQueue(win=Window(data=data), host=q.host, capacity=q.capacity,
                    val_words=q.val_words, checksum=q.checksum), ok)
 
 
 def pop_rpc(q: DQueue, engine: am_mod.AMEngine, n: int,
-            valid: Optional[Array] = None,
-            decision=None) -> Tuple[DQueue, Array, Array]:
+            valid: Optional[Array] = None) -> Tuple[DQueue, Array, Array]:
     P = q.nranks
     dst = _host_dst(q, (P, n))
     payload = jnp.zeros((P, n, 1), dtype=jnp.int32)
     h = engine.handler("q_pop")
     data, replies, delivered = engine.dispatch(h, q.win.data, dst, payload,
-                                               valid, decision=decision)
+                                               valid)
     got = delivered & (replies[..., 0] > 0)
     vals = jnp.where(got[..., None], replies[..., 1:], 0)
     return (DQueue(win=Window(data=data), host=q.host, capacity=q.capacity,
@@ -556,16 +561,17 @@ def push(q, vals, *, promise=Promise.CRW, backend=Backend.AUTO, engine=None,
     Returns (queue', pushed (P, n) bool). Bit-identical visible results
     across backends (tests/test_conformance.py); tracer-safe (the hosted
     queue's skew is `nranks` by construction, so AUTO needs no host read)."""
-    if promise == Promise.CL:
-        return push_local(q, vals, **kw)
-    backend = as_backend(backend)
-    if backend == Backend.AUTO:
-        from . import adaptive as ad
-        a = adaptive or ad.default_engine(q.nranks, am_engine=engine)
-        return a.q_push(q, vals, promise=promise, **kw)
-    if backend == Backend.RPC:
-        return push_rpc(q, engine, vals, valid=kw.get("valid"))
-    return push_rdma(q, vals, promise=promise, **kw)
+    with trace.root("push"):
+        if promise == Promise.CL:
+            return push_local(q, vals, **kw)
+        backend = as_backend(backend)
+        if backend == Backend.AUTO:
+            from . import adaptive as ad
+            a = adaptive or ad.default_engine(q.nranks, am_engine=engine)
+            return a.q_push(q, vals, promise=promise, **kw)
+        if backend == Backend.RPC:
+            return push_rpc(q, engine, vals, valid=kw.get("valid"))
+        return push_rdma(q, vals, promise=promise, **kw)
 
 
 def pop(q, n, *, promise=Promise.CR, backend=Backend.AUTO, engine=None,
@@ -575,16 +581,17 @@ def pop(q, n, *, promise=Promise.CR, backend=Backend.AUTO, engine=None,
     Returns (queue', got (P, n) bool, vals (P, n, val_words) int32) — vals
     are zeros where got is False (the cross-backend contract pinned by
     tests/test_conformance.py)."""
-    if promise == Promise.CL:
-        return pop_local(q, n)
-    backend = as_backend(backend)
-    if backend == Backend.AUTO:
-        from . import adaptive as ad
-        a = adaptive or ad.default_engine(q.nranks, am_engine=engine)
-        return a.q_pop(q, n, promise=promise, **kw)
-    if backend == Backend.RPC:
-        return pop_rpc(q, engine, n, valid=kw.get("valid"))
-    return pop_rdma(q, n, promise=promise, **kw)
+    with trace.root("pop"):
+        if promise == Promise.CL:
+            return pop_local(q, n)
+        backend = as_backend(backend)
+        if backend == Backend.AUTO:
+            from . import adaptive as ad
+            a = adaptive or ad.default_engine(q.nranks, am_engine=engine)
+            return a.q_pop(q, n, promise=promise, **kw)
+        if backend == Backend.RPC:
+            return pop_rpc(q, engine, n, valid=kw.get("valid"))
+        return pop_rdma(q, n, promise=promise, **kw)
 
 
 # ---------------------------------------------------------------------------

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.scan import segmented_scan
+from . import trace
 
 # ---------------------------------------------------------------------------
 # Sharding hook: the launch layer installs a constraint function so that the
@@ -123,10 +124,16 @@ def exchange(x: jax.Array, role: str = "exchange") -> jax.Array:
     """Transpose the (src, dst) leading axes: each rank receives the buckets
     addressed to it. With the leading axis sharded over the owner mesh axes
     this lowers to a single all-to-all; on one device it is a transpose.
+
+    Counted as `exchanges` (one per eager call, per trip inside a traced
+    loop body: `trace.per_trip`); its device ops carry the named scope
+    `ds.exchange`.
     """
-    x = _hint(x, role + "_pre")
-    out = jnp.swapaxes(x, 0, 1)
-    return _hint(out, role + "_post")
+    trace.count("exchanges", of=x)
+    with jax.named_scope("ds.exchange"):
+        x = _hint(x, role + "_pre")
+        out = jnp.swapaxes(x, 0, 1)
+        return _hint(out, role + "_post")
 
 
 # ---------------------------------------------------------------------------
@@ -200,13 +207,14 @@ def make_plan(dst: jax.Array, valid: Optional[jax.Array] = None,
     plan slots are bit-identical to route()'s by construction."""
     nranks, n = dst.shape
     cap = n if cap is None else cap
-    if valid is None:
-        valid = jnp.ones(dst.shape, dtype=bool)
-    empty = jnp.zeros(dst.shape + (0,), dtype=jnp.int32)
-    binned = jax.vmap(
-        lambda d, p, v: bin_by_dest(d, p, nranks, cap, v))(dst, empty, valid)
-    dst_eff = jnp.where(valid, dst, nranks)
-    mask_at_owner = exchange(binned.mask, role + "_mask")
+    with trace.span("plan"), jax.named_scope("ds.plan"):
+        if valid is None:
+            valid = jnp.ones(dst.shape, dtype=bool)
+        empty = jnp.zeros(dst.shape + (0,), dtype=jnp.int32)
+        binned = jax.vmap(lambda d, p, v: bin_by_dest(d, p, nranks, cap, v))(
+            dst, empty, valid)
+        dst_eff = jnp.where(valid, dst, nranks)
+        mask_at_owner = exchange(binned.mask, role + "_mask")
     return RoutePlan(dst_eff=dst_eff, op_slot=binned.op_slot,
                      op_ok=binned.op_ok, mask=mask_at_owner,
                      dropped=binned.dropped, cap=cap)
@@ -229,28 +237,29 @@ def make_plan_np(dst, valid=None, cap: Optional[int] = None,
     pinned by tests/test_pipeline.py.
     """
     import numpy as np
-    dst = np.asarray(dst)
-    nranks, n = dst.shape
-    cap = n if cap is None else cap
-    valid = (np.ones(dst.shape, dtype=bool) if valid is None
-             else np.asarray(valid).astype(bool))
-    dst_eff = np.where(valid, dst, nranks).astype(np.int32)
-    op_slot = np.zeros((nranks, n), np.int32)
-    op_ok = np.zeros((nranks, n), bool)
-    mask = np.zeros((nranks, nranks, cap), bool)
-    dropped = np.zeros((nranks,), np.int32)
-    for r in range(nranks):
-        order = np.argsort(dst_eff[r], kind="stable")
-        dst_s = dst_eff[r][order]
-        group_start = np.searchsorted(dst_s, dst_s, side="left")
-        pos = (np.arange(n) - group_start).astype(np.int32)
-        ok = (pos < cap) & (dst_s < nranks)
-        sel = ok
-        mask[r][dst_s[sel], pos[sel]] = True
-        op_slot[r][order] = pos
-        op_ok[r][order] = ok
-        dropped[r] = int(valid[r].sum()) - int(ok.sum())
-    mask_at_owner = exchange(jnp.asarray(mask), role + "_mask")
+    with trace.span("plan"), jax.named_scope("ds.plan"):
+        dst = np.asarray(dst)
+        nranks, n = dst.shape
+        cap = n if cap is None else cap
+        valid = (np.ones(dst.shape, dtype=bool) if valid is None
+                 else np.asarray(valid).astype(bool))
+        dst_eff = np.where(valid, dst, nranks).astype(np.int32)
+        op_slot = np.zeros((nranks, n), np.int32)
+        op_ok = np.zeros((nranks, n), bool)
+        mask = np.zeros((nranks, nranks, cap), bool)
+        dropped = np.zeros((nranks,), np.int32)
+        for r in range(nranks):
+            order = np.argsort(dst_eff[r], kind="stable")
+            dst_s = dst_eff[r][order]
+            group_start = np.searchsorted(dst_s, dst_s, side="left")
+            pos = (np.arange(n) - group_start).astype(np.int32)
+            ok = (pos < cap) & (dst_s < nranks)
+            sel = ok
+            mask[r][dst_s[sel], pos[sel]] = True
+            op_slot[r][order] = pos
+            op_ok[r][order] = ok
+            dropped[r] = int(valid[r].sum()) - int(ok.sum())
+        mask_at_owner = exchange(jnp.asarray(mask), role + "_mask")
     return RoutePlan(dst_eff=jnp.asarray(dst_eff),
                      op_slot=jnp.asarray(op_slot),
                      op_ok=jnp.asarray(op_ok), mask=mask_at_owner,
@@ -345,12 +354,13 @@ def route_replies(routed: Routed, replies: jax.Array, dst: jax.Array,
     dst:     (P, n) original destination ranks
     returns: (P, n, W) reply words per original op (garbage where ~op_ok)
     """
-    back = exchange(replies, role)                 # (P_origin, P_owner, cap, W)
+    with jax.named_scope("ds.reply"):
+        back = exchange(replies, role)             # (P_origin, P_owner, cap, W)
 
-    def gather_one(back_r, dst_r, slot_r):
-        return back_r[dst_r, slot_r]               # (n, W)
+        def gather_one(back_r, dst_r, slot_r):
+            return back_r[dst_r, slot_r]           # (n, W)
 
-    return jax.vmap(gather_one)(back, dst, routed.op_slot)
+        return jax.vmap(gather_one)(back, dst, routed.op_slot)
 
 
 # ---------------------------------------------------------------------------
@@ -548,8 +558,9 @@ def coalesce_plan(dst: jax.Array, off: jax.Array,
     lexsort, plan occupancy exchanged ONCE for the representative rows
     only — the wire and the owner lanes see distinct rows from the first
     phase on."""
-    co = coalesce(dst, off, match=match, valid=valid)
-    plan = make_plan(dst, valid=co.rep, cap=cap, role=role)
+    with trace.span("plan"), jax.named_scope("ds.plan"):
+        co = coalesce(dst, off, match=match, valid=valid)
+        plan = make_plan(dst, valid=co.rep, cap=cap, role=role)
     return CoalescedPlan(plan=plan, co=co)
 
 
@@ -569,11 +580,12 @@ def miss_subset_plan(dst: jax.Array, off: jax.Array, hit: Optional[jax.Array],
     rows. Still ONE occupancy exchange; all-hit batches should skip the
     plan entirely (zero exchanges) — the caller's job, since building any
     plan costs the occupancy exchange."""
-    if hit is not None:
-        hit = jnp.asarray(hit)
-        valid = ~hit if valid is None else (jnp.asarray(valid) & ~hit)
-    return coalesce_plan(dst, off, match=match, valid=valid, cap=cap,
-                         role=role)
+    with trace.span("plan"), jax.named_scope("ds.plan"):
+        if hit is not None:
+            hit = jnp.asarray(hit)
+            valid = ~hit if valid is None else (jnp.asarray(valid) & ~hit)
+        return coalesce_plan(dst, off, match=match, valid=valid, cap=cap,
+                             role=role)
 
 
 def flatten_owner_view(routed: Routed):

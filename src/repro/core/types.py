@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import trace
+
 
 def host_value(x) -> Optional[np.ndarray]:
     """Host copy of `x`, or None when `x` is absent or a jit tracer.
@@ -24,10 +26,23 @@ def host_value(x) -> Optional[np.ndarray]:
     The front-ends read batch signals (skew, dedup, cache keys) on the
     host when the batch is concrete and degrade to static choices under
     tracing. Any other failure to read a device value (a failed or
-    out-of-memory computation) propagates."""
+    out-of-memory computation) propagates. Reading a device array is
+    the span `ds.host_read` and the counter `host_reads`."""
     if x is None or isinstance(x, jax.core.Tracer):
         return None
-    return np.asarray(x)
+    if not isinstance(x, jax.Array):
+        return np.asarray(x)
+    with trace.span("host_read"):
+        trace.count("host_reads")
+        return np.asarray(x)
+
+
+def device_wait(x):
+    """`jax.block_until_ready(x)`, traced as a host read: the span
+    `ds.host_read` and the counter `host_reads`."""
+    with trace.span("host_read"):
+        trace.count("host_reads")
+        return jax.block_until_ready(x)
 
 
 class Promise(enum.Enum):

@@ -34,15 +34,17 @@ import contextlib
 import functools
 import weakref
 from dataclasses import dataclass
-from typing import List, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from . import faults as flt
 from . import routing
+from . import trace
 from ..kernels.scan import segmented_scan
-from .types import AmoKind, host_value
+from .trace import drain_phase_log  # noqa: F401  (the log's public name)
+from .types import AmoKind
 
 Array = jax.Array
 
@@ -52,8 +54,7 @@ Array = jax.Array
 # inside the scope is logged as (role, decision) so benchmarks can attribute
 # phases to the arm that issued them. Logging happens at trace time — the
 # adaptive layer dispatches arms at the Python level, once per batch. The
-# log is a bounded ring (library callers on the default AUTO path never
-# drain it; unbounded growth would leak).
+# log is `trace`'s bounded phase-log ring, drained by `drain_phase_log`.
 # ---------------------------------------------------------------------------
 _CURRENT_DECISION = None
 # Pipeline slot tagging (DESIGN.md §7): core/pipeline.py wraps each staged
@@ -87,10 +88,6 @@ def note_pipeline_inflight(pipe, active: bool) -> None:
 def pipeline_inflight() -> bool:
     """True while ANY pipeline holds unforced in-flight batches."""
     return len(_INFLIGHT_PIPES) > 0
-# Explicit bound on the diagnostic ring: phases beyond this are dropped
-# oldest-first (library callers on the default AUTO path never drain it).
-PHASE_LOG_MAX = 4096
-_PHASE_LOG: List[Tuple[str, object, Optional[dict]]] = []
 
 
 @contextlib.contextmanager
@@ -151,9 +148,7 @@ def log_cache_event(role: str, info: Optional[dict] = None) -> None:
     merged = dict(info or {})
     if _CURRENT_SLOT is not None:
         merged["slot"], merged["seq"] = _CURRENT_SLOT
-    _PHASE_LOG.append((role, _CURRENT_DECISION, merged or None))
-    if len(_PHASE_LOG) > PHASE_LOG_MAX:
-        del _PHASE_LOG[:-PHASE_LOG_MAX]
+    trace.log_phase(role, _CURRENT_DECISION, merged or None)
 
 
 def _notify_publish(dst: Array, off: Array,
@@ -164,30 +159,15 @@ def _notify_publish(dst: Array, off: Array,
         _CURRENT_CACHE.on_publish(dst, off, valid)
 
 
-def drain_phase_log() -> List[Tuple[str, object, Optional[dict]]]:
-    """Return and clear the (role, decision, info) log of tagged phases.
-
-    Phases are logged while a `decision_scope` and/or a `slot_scope` is
-    active. `info` is None for plain uncoalesced phases; coalesced phases
-    record the sender-side combining stats {"coalesced": True, "rows_in",
-    "rows_out", "dedup_ratio"} when the batch is concrete (host-side ints;
-    absent under jit tracing, where only {"coalesced": True} is recorded),
-    and phases issued inside a pipeline slot additionally carry
-    {"slot": int, "seq": int} (DESIGN.md §7)."""
-    out = list(_PHASE_LOG)
-    _PHASE_LOG.clear()
-    return out
-
-
 def _coalesce_info(co: Optional[routing.Coalescing]) -> Optional[dict]:
+    """Coalescing stats of one phase, kept on the device: the log reads
+    them at `drain_phase_log`, never on the hot path."""
     if co is None:
         return None
-    rows_in, rows_out = host_value(co.rows_in), host_value(co.rows_out)
-    if rows_in is None or rows_out is None:  # tracers: stats stay on-device
+    if isinstance(co.rows_in, jax.core.Tracer):  # stats stay in the trace
         return {"coalesced": True}
-    ri, ro = int(rows_in.sum()), int(rows_out.sum())
-    return {"coalesced": True, "rows_in": ri, "rows_out": ro,
-            "dedup_ratio": ro / max(ri, 1)}
+    return {"coalesced": True, "rows_in": co.rows_in,
+            "rows_out": co.rows_out}
 
 
 @functools.partial(jax.tree_util.register_dataclass, data_fields=["data"],
@@ -352,9 +332,7 @@ def _route_phase(dst: Array, payload: Array, cap: int,
                  role: str,
                  co: Optional[routing.Coalescing] = None) -> routing.Routed:
     if _CURRENT_DECISION is not None or _CURRENT_SLOT is not None:
-        _PHASE_LOG.append((role, _CURRENT_DECISION, _phase_info(co)))
-        if len(_PHASE_LOG) > PHASE_LOG_MAX:
-            del _PHASE_LOG[:-PHASE_LOG_MAX]
+        trace.log_phase(role, _CURRENT_DECISION, _phase_info(co))
     plane = flt.active_plane()
     if plane is not None:
         # DESIGN.md §10: the fault plane simulates wire loss + origin
@@ -413,7 +391,8 @@ def rdma_put(win: Window, dst: Array, off: Array, vals: Array,
                           co=co)
     flat, mask = routing.flatten_owner_view(routed)
     offs, vwords = flat[..., 0], flat[..., 1:1 + V]
-    new_data = jax.vmap(apply_put_local)(win.data, offs, vwords, mask)
+    with jax.named_scope("ds.owner_apply"):
+        new_data = jax.vmap(apply_put_local)(win.data, offs, vwords, mask)
     return Window(data=new_data)
 
 
@@ -437,7 +416,8 @@ def rdma_get(win: Window, dst: Array, off: Array, width: int,
         vals = gather_local(local, offs, width)
         return jnp.where(m[:, None], vals, 0)
 
-    vals = jax.vmap(owner_gather)(win.data, flat[..., 0], mask)
+    with jax.named_scope("ds.owner_apply"):
+        vals = jax.vmap(owner_gather)(win.data, flat[..., 0], mask)
     replies = routing.unflatten_owner_view(vals, win.nranks, cap)
     out = routing.route_replies(routed, replies, dst, role="get_rep")
     if co is not None:
@@ -497,11 +477,12 @@ def rdma_fao(win: Window, dst: Array, off: Array, operand: Array,
     def owner_apply(local, p, m):
         return apply_fao_local(local, p[:, 0], p[:, 1], m, int(kind))
 
-    if _use_kernel_lane():
-        old_flat, new_data = _kernel_amo(win.data, flat, mask, int(kind),
-                                         a_col=1, b_col=None)
-    else:
-        old_flat, new_data = jax.vmap(owner_apply)(win.data, flat, mask)
+    with jax.named_scope("ds.owner_apply"):
+        if _use_kernel_lane():
+            old_flat, new_data = _kernel_amo(win.data, flat, mask, int(kind),
+                                             a_col=1, b_col=None)
+        else:
+            old_flat, new_data = jax.vmap(owner_apply)(win.data, flat, mask)
     replies = routing.unflatten_owner_view(old_flat[..., None], win.nranks,
                                            cap)
     old = routing.route_replies(routed, replies, dst, role="fao_rep")[..., 0]
@@ -534,11 +515,13 @@ def rdma_cas(win: Window, dst: Array, off: Array, cmp: Array, new: Array,
     def owner_apply(local, p, m):
         return apply_cas_local(local, p[:, 0], p[:, 1], p[:, 2], m)
 
-    if _use_kernel_lane():
-        old_flat, new_data = _kernel_amo(win.data, flat, mask,
-                                         int(AmoKind.CAS), a_col=1, b_col=2)
-    else:
-        old_flat, new_data = jax.vmap(owner_apply)(win.data, flat, mask)
+    with jax.named_scope("ds.owner_apply"):
+        if _use_kernel_lane():
+            old_flat, new_data = _kernel_amo(win.data, flat, mask,
+                                             int(AmoKind.CAS), a_col=1,
+                                             b_col=2)
+        else:
+            old_flat, new_data = jax.vmap(owner_apply)(win.data, flat, mask)
     replies = routing.unflatten_owner_view(old_flat[..., None], win.nranks,
                                            cap)
     old = routing.route_replies(routed, replies, dst, role="cas_rep")[..., 0]
@@ -671,12 +654,14 @@ def _fused_phase(win: Window, dst: Array, desc: Array, reply_width: int,
     cap = plan.cap if plan is not None else _default_cap(dst, cap)
     routed = _route_phase(dst, desc, cap, valid, plan, role=role, co=co)
     flat, mask = routing.flatten_owner_view(routed)
-    if _use_kernel_lane():
-        from ..kernels import ops as kops
-        reply_flat, new_data = kops.fused_apply(
-            win.data, flat, mask, reply_width=reply_width, use_pallas=True)
-    else:
-        reply_flat, new_data = xla_apply(win.data, flat, mask)
+    with jax.named_scope("ds.owner_apply"):
+        if _use_kernel_lane():
+            from ..kernels import ops as kops
+            reply_flat, new_data = kops.fused_apply(
+                win.data, flat, mask, reply_width=reply_width,
+                use_pallas=True)
+        else:
+            reply_flat, new_data = xla_apply(win.data, flat, mask)
     replies = routing.unflatten_owner_view(reply_flat, win.nranks, cap)
     out = routing.route_replies(routed, replies, dst, role=role + "_rep")
     if co is not None:
@@ -791,8 +776,9 @@ def rdma_txn_commit(win: Window, dst: Array, desc: Array,
                           role="txn_commit")
     flat, mask = routing.flatten_owner_view(routed)
     from ..kernels import ops as kops
-    reply_flat, new_data = kops.txn_group_apply(win.data, flat, mask,
-                                                ngroups=win.nranks)
+    with jax.named_scope("ds.owner_apply"):
+        reply_flat, new_data = kops.txn_group_apply(win.data, flat, mask,
+                                                    ngroups=win.nranks)
     replies = routing.unflatten_owner_view(reply_flat, win.nranks, cap)
     out = routing.route_replies(routed, replies, dst, role="txn_commit_rep")
     return out, Window(data=new_data)

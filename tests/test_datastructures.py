@@ -8,7 +8,7 @@ import pytest
 from repro.core import am as am_mod
 from repro.core import hashtable as ht_mod
 from repro.core import queue as q_mod
-from repro.core import routing, window
+from repro.core import routing, trace, window
 from repro.core.types import AmoKind, Promise
 
 
@@ -401,20 +401,20 @@ def test_queue_local_promise_zero_phases():
 
 
 # ---------------------------------------------------------------------------
-# Diagnostic rings (ISSUE 4 satellite): explicit bounds + drain, and
-# coalescing stats recorded into decision_scope entries.
+# The phase log: an explicit bound + drain, and coalescing stats recorded
+# into decision_scope entries.
 # ---------------------------------------------------------------------------
 def test_window_phase_log_bounded_and_drains(monkeypatch):
     win = window.make_window(P, 32)
     dst = jnp.zeros((P, 4), jnp.int32)
     off = jnp.zeros((P, 4), jnp.int32)
     window.drain_phase_log()
-    monkeypatch.setattr(window, "PHASE_LOG_MAX", 5)
+    monkeypatch.setattr(trace, "PHASE_LOG_MAX", 3)
     with window.decision_scope("dec"):
-        for _ in range(4):  # 4 FAOs x 1 logged phase each... (role="fao")
+        for _ in range(4):  # 4 FAOs x 1 logged phase each (role="fao")
             _, win = window.rdma_fao(win, dst, off, 1, AmoKind.FAA)
     log = window.drain_phase_log()
-    assert len(log) <= 5          # bounded: oldest entries dropped
+    assert len(log) == 3          # bounded: the oldest entry dropped
     assert window.drain_phase_log() == []  # drained
     # outside a decision scope nothing is logged
     window.rdma_fao(win, dst, off, 1, AmoKind.FAA)
@@ -437,24 +437,3 @@ def test_phase_log_records_coalescing_stats():
     assert info_a["rows_in"] == P * 6
     assert info_a["rows_out"] == P              # one rep per origin
     assert info_a["dedup_ratio"] == pytest.approx(1 / 6)
-
-
-def test_am_dispatch_log_bounded_and_drains():
-    eng = am_mod.AMEngine(P, dispatch_log_max=3)
-    echo = eng.register("echo", lambda l, p, m: (l, p[:, :1]),
-                        reply_width=1)
-    state = jnp.zeros((P, 4), jnp.int32)
-    dst = jnp.zeros((P, 2), jnp.int32)
-    payload = jnp.ones((P, 2, 1), jnp.int32)
-    for i in range(5):
-        eng.dispatch(echo, state, dst, payload, decision=f"d{i}")
-    assert len(eng.dispatch_log) == 3           # bounded ring
-    names = [d for _, d, _ in eng.dispatch_log]
-    assert names == ["d2", "d3", "d4"]          # oldest dropped
-    drained = eng.drain_dispatch_log()
-    assert len(drained) == 3
-    assert len(eng.dispatch_log) == 0
-    # coalesced dispatch records its combining stats
-    eng.dispatch(echo, state, dst, payload, decision="dc", coalesce=True)
-    (_, _, info), = eng.drain_dispatch_log()
-    assert info["coalesced"] is True and info["rows_out"] == P
