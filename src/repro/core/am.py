@@ -4,7 +4,9 @@ The TPU-native realization of GASNet-EX style active messages (DESIGN.md §2):
 
 - `dispatch` = ONE request exchange + arbitrary shard-local handler + ONE
   reply exchange. The number of network phases is *independent of the
-  handler's control flow* — the paper's central RPC property.
+  handler's control flow* — the paper's central RPC property. Routing,
+  handler and reply run as one jitted program, compiled once per handler,
+  shapes and flags and kept on the engine.
 - Handlers obey the paper's AM restrictions by construction: they are pure
   shard-local JAX functions, so they cannot send further messages or touch
   the network.
@@ -49,6 +51,33 @@ class Handler:
                                   Tuple[Any, Array]]] = None
 
 
+@dataclass(eq=False)
+class _Program:
+    """One cached dispatch program: the jitted core, the sharding hook it
+    is traced under (held so that the hook's id in the key stays unique)
+    and the number of times its Python body has been traced."""
+
+    hook: Callable
+    fn: Optional[Callable] = None
+    traces: int = 0
+
+    @property
+    def count_key(self) -> tuple:
+        """Key of the counts the core makes while traced (`trace.per_trip`).
+        A program always traces before its first count is read, so an id
+        reused after this program is gone is overwritten before use."""
+        return ("am.dispatch", id(self))
+
+
+def _first_tracer(tree) -> Optional[Array]:
+    """The first jit tracer among the leaves of `tree`, or None: a
+    dispatch called inside an outer trace is traced there, not run."""
+    for x in jax.tree_util.tree_leaves(tree):
+        if isinstance(x, jax.core.Tracer):
+            return x
+    return None
+
+
 class AMEngine:
     """Handler registry + dispatch. One engine per distributed structure."""
 
@@ -66,6 +95,8 @@ class AMEngine:
         # with the inter-submit busy_wait knob this makes attentiveness a
         # measurable quantity (benchmarks/pipeline_bench.py).
         self.dispatch_points = 0
+        # compiled dispatch programs, by `_program`'s key
+        self._programs: dict[tuple, _Program] = {}
 
     # -- deferred dispatch (pipeline integration, DESIGN.md §7) ------------
     @property
@@ -141,8 +172,6 @@ class AMEngine:
         plan:    optional precomputed RoutePlan (routing.make_plan) — callers
                  issuing repeated dispatches to fixed destinations reuse one
                  plan per batch and skip the per-dispatch routing sort
-        decision: optional adaptive.Decision that chose this dispatch —
-                 recorded in `self.dispatch_log` for benchmark attribution
         coalesce: dedup IDENTICAL request rows to the same destination
                  sender-side — the handler sees one combined row per
                  duplicate run and its reply fans out to every duplicate
@@ -157,11 +186,17 @@ class AMEngine:
         is derivable locally from `delivered`, matching the paper's
         counter-increment reply elision).
 
-        Traced as the spans `ds.am.request` (routing), `ds.owner_apply`
-        (the handler, also a named scope on its device ops) and
-        `ds.am.reply`.
+        The fault plane acts on the host at every call; the rest (routing,
+        handler, reply) is one jitted program per (handler, cap, coalesce,
+        plan given, valid given, sharding hook), kept on the engine and
+        reused for every batch of the same shapes. Traced as the span
+        `ds.am.dispatch`; counts `am_dispatches` per call run and
+        `am_dispatch_traces` per call that traced its program (neither
+        inside an outer jit trace, where the outer program is what runs);
+        the program's device ops carry the named scopes `ds.exchange`,
+        `ds.owner_apply` and `ds.reply`.
         """
-        with trace.span("am.request"):
+        with trace.span("am.dispatch"):
             plane = flt.active_plane()
             if plane is not None:
                 # DESIGN.md §10, applied pre-coalescing at op-row
@@ -171,6 +206,44 @@ class AMEngine:
                 # retransmit + dedup simulation.
                 valid = plane.inject_am(dst, valid)
                 plane.tick()
+            if plan is not None:
+                cap = plan.cap
+            elif cap is None:
+                cap = dst.shape[1]
+            prog = self._program(handler, cap, coalesce, plan is not None,
+                                 valid is not None)
+            traces = prog.traces
+            out = prog.fn(state, dst, payload, valid, plan)
+            outer = _first_tracer((state, dst, payload, valid, plan))
+            trace.count("am_dispatches", of=outer)
+            if prog.traces != traces:
+                trace.count("am_dispatch_traces", of=outer)
+            trace.trips(prog.count_key, 1, of=outer)
+            return out
+
+    def _program(self, handler: Handler, cap: int, coalesce: bool,
+                 planned: bool, has_valid: bool) -> _Program:
+        """The cached dispatch program for these static choices, built on
+        first use. The sharding hook is read while the program is traced,
+        so its identity is part of the key, and the entry keeps the hook
+        alive so that its id is not reused."""
+        hook = routing.current_sharding_hook()
+        key = (handler, cap, coalesce, planned, has_valid, id(hook))
+        prog = self._programs.get(key)
+        if prog is None:
+            prog = self._programs[key] = _Program(hook=hook)
+            prog.fn = jax.jit(trace.per_trip(
+                prog.count_key, self._core(handler, cap, coalesce, prog)))
+        return prog
+
+    def _core(self, handler: Handler, cap: int, coalesce: bool,
+              prog: _Program):
+        """The pure part of a dispatch: request routing (coalesced when
+        asked), the handler, reply routing and fan-out."""
+        nranks = self.nranks
+
+        def core(state, dst, payload, valid, plan):
+            prog.traces += 1
             co = None
             eff_valid = valid
             if coalesce:
@@ -178,22 +251,19 @@ class AMEngine:
                                       valid=valid)
                 eff_valid = co.rep if valid is None else (valid & co.rep)
             if plan is not None:
-                cap = plan.cap
                 routed = routing.route_with_plan(plan, payload,
                                                  active=eff_valid,
                                                  role="am_req")
             else:
-                cap = dst.shape[1] if cap is None else cap
                 routed = routing.route(dst, payload, cap, eff_valid,
                                        role="am_req")
             flat, mask = routing.flatten_owner_view(routed)
-
-        with trace.span("owner_apply"), jax.named_scope("ds.owner_apply"):
-            if handler.batched_fn is not None:
-                state2, reply_flat = handler.batched_fn(state, flat, mask)
-            else:
-                state2, reply_flat = jax.vmap(handler.fn)(state, flat, mask)
-        with trace.span("am.reply"):
+            with jax.named_scope("ds.owner_apply"):
+                if handler.batched_fn is not None:
+                    state2, reply_flat = handler.batched_fn(state, flat, mask)
+                else:
+                    state2, reply_flat = jax.vmap(handler.fn)(state, flat,
+                                                              mask)
             delivered = routed.op_ok
             if co is not None:
                 # reply fan-out: duplicates are delivered iff their
@@ -204,13 +274,15 @@ class AMEngine:
             if handler.reply_width == 0:
                 replies = jnp.zeros(dst.shape + (0,), dtype=jnp.int32)
                 return state2, replies, delivered
-            replies_o = routing.unflatten_owner_view(reply_flat, self.nranks,
-                                                     cap)
+            replies_o = routing.unflatten_owner_view(reply_flat, nranks, cap)
             replies = routing.route_replies(routed, replies_o, dst,
                                             role="am_rep")
             if co is not None:
                 replies = routing.lead(co, replies)
             return state2, replies, delivered
+
+        core.__name__ = f"am_{handler.name}"     # the device program's name
+        return core
 
     def dispatch_local(self, handler: Handler, state: Any, payload: Array,
                        valid: Optional[Array] = None

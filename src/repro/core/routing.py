@@ -54,6 +54,12 @@ def sharding_hook(fn):
         _SHARD_HOOK = prev
 
 
+def current_sharding_hook() -> Callable[[jax.Array, str], jax.Array]:
+    """The hook in force: a program traced now reads this one, so a cache
+    of traced programs keys on its identity (`am.AMEngine`)."""
+    return _SHARD_HOOK
+
+
 def _hint(x: jax.Array, role: str) -> jax.Array:
     return _SHARD_HOOK(x, role)
 

@@ -19,7 +19,8 @@ the device. A loop traced once and run for a trip count known only on the
 device counts through `per_trip` and `trips`: the counts its body makes
 while it is traced are kept per trip under a key, and each run adds them
 times its trip count. The kept counts outlive the trace, so a loop whose
-trace is cached still counts every run.
+trace is cached still counts every run. A jitted program that is not a
+loop counts the same way, with one trip per call (`am.AMEngine.dispatch`).
 
 When tracing is off every `span`, `count` and `trips` call is one bool
 test that returns at once, and no annotation object is built.
@@ -31,6 +32,7 @@ decision or slot scope.
 from __future__ import annotations
 
 import contextlib
+import functools
 import gc
 import threading
 import time
@@ -179,6 +181,7 @@ def per_trip(key: Hashable, body: Callable) -> Callable:
     """`body` of a loop, wrapped so that the counts made while it is
     traced are kept as one trip's counts under `key`. `key` names the
     loop and every static choice that changes what its body counts."""
+    @functools.wraps(body)
     def traced(*args):
         global _live
         _bodies.append({})
@@ -193,13 +196,13 @@ def per_trip(key: Hashable, body: Callable) -> Callable:
     return traced
 
 
-def trips(key: Hashable, n) -> None:
+def trips(key: Hashable, n, of=None) -> None:
     """The loop kept under `key` ran `n` trips (an int or a device
-    scalar): add its per-trip counts times `n`."""
+    scalar): add its per-trip counts times `n`. `of` is as in `count`."""
     if not _live:
         return
     for name, k in _per_trip.get(key, {}).items():
-        count(name, k, n)
+        count(name, k, n, of=of)
 
 
 def take() -> dict:

@@ -115,6 +115,22 @@ def test_am_dispatch_exchange_table():
     assert c.mask_exchanges() == 1
 
 
+def test_cached_dispatch_is_traced_again_under_a_new_hook():
+    """A dispatch program cached under one sharding hook is traced anew
+    under another: the hook is read while tracing, so a reused program
+    would carry the old hook's constraints (and count nothing here)."""
+    dst, _, _, vals = _fixtures()
+    eng = am_mod.AMEngine(P)
+    echo = eng.register("echo", lambda l, p, m: (l, p[:, :1]),
+                        reply_width=1)
+    state = jnp.zeros((P, 4), jnp.int32)
+    jax.block_until_ready(eng.dispatch(echo, state, dst, vals))
+    c = ExchangeCounter()
+    assert c.run(lambda: eng.dispatch(echo, state, dst, vals)) == 3
+    assert c.mask_exchanges() == 1
+    assert len(eng._programs) == 2
+
+
 def test_planned_batch_has_one_occupancy_exchange():
     """A planned probe loop exchanges the occupancy mask exactly ONCE per
     batch (at plan time); every subsequent phase ships payload only."""

@@ -105,9 +105,16 @@ def _batches(seed):
         q_mod.pop(q2, N, adaptive=ad)
 
     trace.take()                             # leave out the set-up
-    return [(insert, {"exchanges": 3, "host_reads": 2}),
+    return [(insert, {"exchanges": 3, "host_reads": 2, "am_dispatches": 1}),
             (find, {"exchanges": 1 + 2 * trips, "probe_trips": trips}),
-            (push_pop, {"exchanges": 6})]
+            (push_pop, {"exchanges": 6, "am_dispatches": 2})]
+
+
+def _first_run(want):
+    """The counts of a batch's first run on a fresh engine: each AM
+    dispatch traces its program once, later runs reuse it."""
+    n = want.get("am_dispatches", 0)
+    return dict(want, am_dispatch_traces=n) if n else want
 
 
 def test_off_records_nothing_and_builds_no_annotation(recorder):
@@ -125,7 +132,7 @@ def test_off_records_nothing_and_builds_no_annotation(recorder):
 def test_counts_by_hand(traced, which):
     run, want = _batches(2)[which]
     run()
-    assert trace.take()["counts"] == want
+    assert trace.take()["counts"] == _first_run(want)
 
 
 def _no_gc(spans):
@@ -139,7 +146,7 @@ def test_same_batch_twice_counts_twice(traced):
         run()
         run()
         twice = trace.take()
-        assert once["counts"] == want
+        assert once["counts"] == _first_run(want)
         assert twice["counts"] == {k: 2 * v for k, v in want.items()}
         assert _no_gc(twice["spans"]) == {
             k: 2 * v for k, v in _no_gc(once["spans"]).items()}
