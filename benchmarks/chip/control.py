@@ -26,12 +26,13 @@ def readings(name, seeds, seconds, fault_names, *, cell=None, devices=None,
              emit=print):
     from benchmarks.chip import faults, harness
     cell = cell or harness.resolve(name)
-    pattern = cell.mix["pattern"]
+    pattern, chips = cell.mix["pattern"], cell.workload["chips"]
     out = []
     for seed in seeds:
         bench = None
         for fname in [None] + list(fault_names):
-            if fname is not None and not faults.applies(fname, pattern):
+            if fname is not None and not faults.applies(fname, pattern,
+                                                        chips):
                 continue
             if bench is None or not bench.plan.epoch:
                 if bench is not None:
@@ -63,8 +64,8 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--seconds", type=float, default=5.0)
-    ap.add_argument("--faults",
-                    default="control,stale_state,half_batch,altered_answer")
+    ap.add_argument("--faults", default="control,stale_state,half_batch,"
+                    "altered_answer,lost_shard")
     args = ap.parse_args(argv)
     from benchmarks.chip import harness
     cell = harness.resolve(args.workload)

@@ -16,13 +16,16 @@ length of a window:
                   each rank's ops is never issued) while its answers are
                   reported as they come;
   altered_answer  one answer of every batch is changed where it is
-                  produced.
-
-One chip has no exchange between chips, so that fault has no case here.
+                  produced;
+  lost_shard      every call hands back the first chip's shard of the
+                  table as it was before the batch, as if the exchange
+                  between chips had never brought that chip its writes
+                  (cells on more than one chip only).
 """
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 
 import jax.numpy as jnp
 
@@ -104,13 +107,35 @@ def altered_answer(drv):
     return _patched(drv, issue=issue)
 
 
+def lost_shard(drv):
+    def accept(inner, out):
+        answers = inner(out)
+        rows = drv.P // drv.chips
+        old, new = drv._stale.win, drv.state.win
+        data = new.data.at[:rows].set(old.data[:rows])
+        drv.state = dataclasses.replace(
+            drv.state, win=dataclasses.replace(new, data=data))
+        return answers
+
+    def issue(inner, op, slot, **kw):
+        drv._stale = drv.state
+        return inner(op, slot, **kw)
+
+    return _patched(drv, issue=issue, accept=accept)
+
+
 FAULTS = {"control": control, "stale_state": stale_state,
-          "half_batch": half_batch, "altered_answer": altered_answer}
+          "half_batch": half_batch, "altered_answer": altered_answer,
+          "lost_shard": lost_shard}
 
 
-def applies(name: str, pattern) -> bool:
-    """Whether the fault can happen in a cell with this op pattern: a
-    read-only cell has no state change to leave out."""
+def applies(name: str, pattern, chips: int = 1) -> bool:
+    """Whether the fault can happen in a cell with this op pattern on
+    this many chips: a read-only cell has no state change to leave out,
+    and one chip has no exchange between chips."""
+    writes = any(op in ("insert", "push", "pop") for op in pattern)
     if name == "stale_state":
-        return any(op in ("insert", "push", "pop") for op in pattern)
+        return writes
+    if name == "lost_shard":
+        return writes and chips > 1
     return True

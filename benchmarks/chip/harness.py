@@ -23,6 +23,7 @@ import importlib
 import importlib.util
 import json
 import pathlib
+import resource
 import shutil
 import statistics
 import sys
@@ -152,6 +153,7 @@ class Run:
     compile_s: Dict[str, float]
     arms: Dict[str, Dict[str, int]]
     trace: object = None
+    program: Optional[dict] = None
 
 
 class Bench:
@@ -165,9 +167,13 @@ class Bench:
         self.t_start = time.perf_counter() if t_start is None else t_start
         self.cell = cell
         self.devices = devices or jax.devices()
+        chips = int(cell.workload["chips"])
+        if len(self.devices) < chips:
+            raise ValueError(f"the cell runs on {chips} chips, "
+                             f"{len(self.devices)} given")
         self.plan = traffic.Plan(cell.config, cell.mix, seed)
-        self.drv = structure(cell.config["structure"]).Driver(cell.config,
-                                                              self.plan)
+        self.drv = structure(cell.config["structure"]).Driver(
+            cell.config, self.plan, self.devices[:chips])
         self.setup: Dict[str, float] = {}
         self.warm: List[dict] = []
         self.k = 0
@@ -217,13 +223,18 @@ class Bench:
         self.k = 0
 
     def window(self, seconds: float, trace: bool) -> Run:
-        """One closed-loop window of `seconds`."""
+        """One closed-loop window of `seconds`. A traced window runs under
+        the profiler with the program's own spans and counters on
+        (`repro.core.trace`), and keeps their totals over the window."""
         import jax
+        from repro.core import trace as program_trace
         plan, epoch, k0 = self.plan, self.plan.epoch, self.k
         n_log0 = len(self.drv.arms())
         clock = CompileClock()
         tdir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
         if trace:
+            program_trace.enable()
+            program_trace.take()        # drop what set-up counted
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             opts.host_tracer_level = 1
@@ -251,8 +262,10 @@ class Bench:
         window_s = time.perf_counter() - w0
         clock.active = False
         self.k = k
-        summary = None
+        summary = program = None
         if trace:
+            program = program_trace.take()
+            program_trace.disable()
             jax.profiler.stop_trace()
             from benchmarks.chip import tracereduce
             summary = tracereduce.read_dir(tdir)
@@ -266,7 +279,8 @@ class Bench:
         return Run(setup=dict(self.setup), latencies=latencies,
                    window_s=window_s, attempted=attempted, failed=failed,
                    counters=counters, compile_n=clock.n,
-                   compile_s=clock.s, arms=arms, trace=summary)
+                   compile_s=clock.s, arms=arms, trace=summary,
+                   program=program)
 
     def check(self) -> Dict[str, int]:
         """The reference's counts for the last window, the warm-up
@@ -309,13 +323,16 @@ def result_line(cell: Cell, run: Run, trace: bool, dev: dict,
     say(f"compile events in the window: {json.dumps(run.compile_n)}")
     say(f"device {dev}")
     say(f"reference check took {check_s} s")
+    say(f"host peak RSS {resource.getrusage(resource.RUSAGE_SELF).ru_maxrss}"
+        " KiB")
     if run.trace is not None:
         say(f"traced window {run.trace.window_s} s, busy {run.trace.busy_s} s,"
             f" {run.trace.batches} batches; lost to a trace buffer drop: "
             f"{run.trace.dropped_s} s")
     ctx = {"window_s": run.window_s, "batches": len(lat_ms),
            "compile_n": run.compile_n, "compile_s": run.compile_s,
-           "counters": run.counters, "trace": run.trace}
+           "counters": run.counters, "trace": run.trace,
+           "program": run.program}
     metrics: Dict[str, dict] = {}
     if trace:
         for m in cell.per_layer:

@@ -263,7 +263,8 @@ def run_window(cell, seed: int, seconds: float, mode: str,
             run = bench.window(seconds, mode == "profiled")
     finally:
         trace.disable()
-    program = trace.take()
+    # a profiled window keeps the program's totals itself
+    program = run.program if run.program is not None else trace.take()
     checks = bench.check()
     bench.drv.free()
     batches = len(run.latencies)

@@ -15,18 +15,33 @@ program, so one compiled program serves every seed.
 The window calls the normal front-ends, `hashtable.insert` and
 `hashtable.find`, with `backend="auto"` and an `am.AMEngine`, so the
 default chooser picks the arm of every batch.
+
+On more than one chip the rank axis is sharded over them: the table,
+the pools and every front-end call (under `routing.sharding_hook`) keep
+whole rank rows on each chip, so `routing.exchange` is an all-to-all
+between chips. The pre-load (`preload_rows`) then runs on each chip in
+`shard_map`: each chip walks the key range in chunks, keeps the keys
+whose owner row it holds and claims their slots by the same rule, with
+row-local indices only. On one chip the same code places every key, in
+one chunk where the keys fit it, and no mesh, sharding or hook is built
+or issued.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from benchmarks.chip import traffic
 from benchmarks.chip.reference.hashtable import READY
-from repro.core import adaptive, am, hashtable
+from repro.core import adaptive, am, hashtable, routing
+
+# pre-load key indices one chip walks at a time (see `preload_rows`)
+PRELOAD_CHUNK = 1 << 25
 
 
 def place_jnp(keys, nranks: int, nslots: int):
@@ -43,59 +58,154 @@ def place_jnp(keys, nranks: int, nslots: int):
 class Driver:
     kind = "hashtable"
 
-    def __init__(self, config: dict, plan: traffic.Plan):
+    def __init__(self, config: dict, plan: traffic.Plan, devices=None):
         self.config, self.plan = config, plan
         self.P = plan.nranks
         self.nslots = int(config["nslots"])
         self.vw = int(config["val_words"])
         self.max_probes = int(config["max_probes"])
+        self.chips = len(devices) if devices else 1
+        self.shard = self.hook = None
+        if self.chips > 1:
+            if self.P % self.chips:
+                raise ValueError(f"{self.P} ranks do not split evenly over "
+                                 f"{self.chips} chips")
+            self.mesh = Mesh(np.array(devices), ("p",))
+            self.shard = shard = NamedSharding(self.mesh, PartitionSpec("p"))
+
+            # made once: the AM engine's program cache keys on its id
+            def hook(x, role):
+                return jax.lax.with_sharding_constraint(x, shard)
+            self.hook = hook
         self.engine = am.AMEngine(self.P)
         self.chooser = adaptive.default_engine(self.P,
                                                am_engine=self.engine)
 
+    def _sharded(self):
+        """The front-ends' rank axis pinned to the chips, where there are
+        several."""
+        if self.hook is None:
+            return contextlib.nullcontext()
+        return routing.sharding_hook(self.hook)
+
     # -- set-up ---------------------------------------------------------
     def build(self):
         """The pre-loaded table. Returns the device arrays of the
-        pre-load's (placed, probes), one entry per pre-load key."""
-        ht = hashtable.make_hashtable(self.P, self.nslots, self.vw)
-        ht, ok, probes = jax.jit(self.bulk_load, donate_argnums=0)(
-            ht, np.uint32(self.plan.key_base))
+        pre-load's answers, one entry per pre-load key: (placed, probes)."""
+        kb = np.uint32(self.plan.key_base)
+        placed = {} if self.shard is None else {"sharding": self.shard}
+        ht = hashtable.make_hashtable(self.P, self.nslots, self.vw, **placed)
+        ht, ok, probes, over = jax.jit(self.bulk_load,
+                                       donate_argnums=0)(ht, kb)
+        if int(over):
+            raise RuntimeError(f"{int(over)} pre-load keys overflowed a "
+                               "chip's share of a chunk")
         self.start = self.state = ht
         return ok, probes
 
     def bulk_load(self, ht, key_base):
-        plan, P, ns, mp = self.plan, self.P, self.nslots, self.max_probes
-        n = plan.n_pre
-        idx = jnp.arange(n, dtype=jnp.int32)
-        keys = traffic.key_jnp(idx, key_base)
-        owner, start = place_jnp(keys, P, ns)
-        base = owner * ns
-        taken = jnp.zeros(P * ns, bool)
-        slot = jnp.zeros(n, jnp.int32)
-        probes = jnp.zeros(n, jnp.int32)
-        pending = jnp.ones(n, bool)
-        for j in range(mp):
-            s = base + (start + j) % ns
-            bid = jnp.where(pending & ~taken[s], idx, n)
-            first = jnp.full(P * ns, n, jnp.int32).at[s].min(bid)
-            won = pending & (first[s] == idx)
-            taken = taken.at[jnp.where(won, s, P * ns)].set(True,
-                                                            mode="drop")
-            slot = jnp.where(won, s, slot)
-            probes = probes + pending.astype(jnp.int32)
-            pending = pending & ~won
-        # 1-D scatters only: a (slots, 3) array would be padded to 128
-        # lanes on the TPU
-        rec_w = 2 + self.vw
-        at = jnp.where(pending, P * ns, slot) * rec_w
-        data = jnp.zeros(P * ns * rec_w, jnp.int32)
-        data = data.at[at].set(READY, mode="drop")
-        data = data.at[at + 1].set(keys, mode="drop")
-        vals = traffic.value_jnp(keys)
-        for w in range(self.vw):
-            data = data.at[at + 2 + w].set(vals, mode="drop")
-        win = dataclasses.replace(ht.win, data=data.reshape(P, ns * rec_w))
-        return dataclasses.replace(ht, win=win), ~pending, probes
+        """The pre-load: `preload_rows` on the one chip, or on each chip
+        for the rows it holds, each key's answer taken from its owner's
+        chip and the answers split over the chips. Returns the table,
+        placed and probes (int8) per pre-load key, and the count of keys
+        a chip had no room for in its share of a chunk (0, or the
+        pre-load is void)."""
+        n = self.plan.n_pre
+        if self.shard is None:
+            data, signed, over = self.preload_rows(ht.win.data, key_base, 0)
+        else:
+            rows = self.P // self.chips
+            n_pad = -(-n // self.chips) * self.chips
+
+            def per_chip(local, key_base):
+                row0 = jax.lax.axis_index("p") * rows
+                data, signed, over = self.preload_rows(local, key_base, row0,
+                                                       n_pad)
+                # each key is 0 on every chip but its owner's
+                signed = jax.lax.psum_scatter(signed, "p", tiled=True)
+                return data, signed, jax.lax.psum(over, "p")
+
+            spec = PartitionSpec("p")
+            data, signed, over = jax.shard_map(
+                per_chip, mesh=self.mesh, in_specs=(spec, PartitionSpec()),
+                out_specs=(spec, spec, PartitionSpec()),
+                check_vma=False)(ht.win.data, key_base)
+            signed = signed[:n]
+        win = dataclasses.replace(ht.win, data=data)
+        return (dataclasses.replace(ht, win=win), signed > 0,
+                jnp.abs(signed), over)
+
+    def preload_rows(self, local, key_base, row0, n_out=None):
+        """Places the pre-load keys whose owner is one of the rows of
+        `local`, the window's rows from `row0` on, with row-local indices
+        only. Keys go in chunks of `PRELOAD_CHUNK` indices, one chunk
+        when they fit; the rows' share of a chunk (all of it on one chip,
+        about 1/chips of it with room to spare on several) is compacted
+        in index order and claims slots in probe rounds as the program's
+        fused insert claims them, the lowest key index winning a
+        contended slot. A key claims only slots that no earlier chunk
+        took, and a slot once taken stays taken, so every placed key lies
+        in its probe window with no empty slot before it, and a refused
+        key's window is full. Returns the rows, each pre-load key's
+        probes as int8 (negative if refused, 0 for keys of other rows),
+        `n_out` entries of them (the pre-load's count by default, 0
+        after it), and the count of keys the compacted chunk had no room
+        for."""
+        P, ns, mp, vw = self.P, self.nslots, self.max_probes, self.vw
+        rows, n, rec_w = P // self.chips, self.plan.n_pre, 2 + vw
+        local_slots = rows * ns
+        chunk = min(PRELOAD_CHUNK, 1 << max(n - 1, 1).bit_length())
+        nchunks = -(-n // chunk)
+        n_out = n_out or n
+        size = max(nchunks * chunk, n_out)
+        cap = chunk if self.chips == 1 else min(
+            chunk, chunk // self.chips + max(chunk // 64, 1024))
+
+        def one_chunk(c, carry):
+            flat, taken, signed, over = carry
+            lane = jnp.arange(cap, dtype=jnp.int32)
+            idx = c * chunk + jnp.arange(chunk, dtype=jnp.int32)
+            owner, _ = place_jnp(traffic.key_jnp(idx, key_base), P, ns)
+            mine = (idx < n) & (owner >= row0) & (owner < row0 + rows)
+            pos = jnp.where(mine, jnp.cumsum(mine, dtype=jnp.int32) - 1, cap)
+            sel = jnp.full(cap, -1, jnp.int32).at[pos].set(idx, mode="drop")
+            over = over + jnp.maximum(jnp.sum(mine, dtype=jnp.int32) - cap, 0)
+            live = sel >= 0
+            keys = traffic.key_jnp(jnp.maximum(sel, 0), key_base)
+            owner, start = place_jnp(keys, P, ns)
+            base = (owner - row0) * ns
+            slot = jnp.zeros(cap, jnp.int32)
+            probes = jnp.zeros(cap, jnp.int32)
+            pending = live
+            for j in range(mp):
+                s = jnp.where(pending, base + (start + j) % ns, 0)
+                bid = jnp.where(pending & ~taken[s], lane, cap)
+                first = jnp.full(local_slots, cap, jnp.int32).at[s].min(bid)
+                won = pending & (first[s] == lane)
+                taken = taken.at[jnp.where(won, s, local_slots)].set(
+                    True, mode="drop")
+                slot = jnp.where(won, s, slot)
+                probes = probes + pending.astype(jnp.int32)
+                pending = pending & ~won
+            placed = live & ~pending
+            # 1-D scatters only: a (slots, 3) array would be padded to 128
+            # lanes on the TPU
+            at = jnp.where(placed, slot, local_slots) * rec_w
+            flat = flat.at[at].set(READY, mode="drop")
+            flat = flat.at[at + 1].set(keys, mode="drop")
+            vals = traffic.value_jnp(keys)
+            for w in range(vw):
+                flat = flat.at[at + 2 + w].set(vals, mode="drop")
+            signed = signed.at[jnp.where(live, sel, size)].set(
+                jnp.where(placed, probes, -probes).astype(jnp.int8),
+                mode="drop")
+            return flat, taken, signed, over
+
+        carry = (local.reshape(-1), jnp.zeros(local_slots, bool),
+                 jnp.zeros(size, jnp.int8), jnp.int32(0))
+        flat, _, signed, over = jax.lax.fori_loop(0, nchunks, one_chunk,
+                                                  carry)
+        return flat.reshape(local.shape), signed[:n_out], over
 
     @staticmethod
     def insert_pool(idx, key_base):
@@ -114,14 +224,15 @@ class Driver:
         """The window's inputs, one device array per pool batch, made in
         one jitted call per op."""
         plan, kb = self.plan, np.uint32(self.plan.key_base)
+        placed = {} if self.shard is None else {"out_shardings": self.shard}
         self.pools = {}
         if "insert" in plan.pattern:
             idx = np.stack([plan.insert_index(s) for s in range(plan.pool)])
-            self.pools["insert"] = jax.jit(self.insert_pool)(
+            self.pools["insert"] = jax.jit(self.insert_pool, **placed)(
                 jnp.asarray(idx, jnp.int32), kb)
         if "find" in plan.pattern:
             self.find_idx = plan.find_pool_indices()
-            self.pools["find"] = jax.jit(self.find_pool)(
+            self.pools["find"] = jax.jit(self.find_pool, **placed)(
                 jnp.asarray(self.find_idx), kb)
         jax.block_until_ready(self.pools)
 
@@ -129,17 +240,18 @@ class Driver:
     def issue(self, op: str, slot: int, **kw):
         """One front-end call. Returns (ops issued, device outputs);
         the outputs' first entry is the new state."""
-        if op == "insert":
-            keys, vals = self.pools["insert"][slot]
-            out = hashtable.insert(self.state, keys, vals,
-                                   engine=self.engine,
-                                   max_probes=self.max_probes, **kw)
-        elif op == "find":
-            out = hashtable.find(self.state, self.pools["find"][slot],
-                                 engine=self.engine,
-                                 max_probes=self.max_probes, **kw)
-        else:
-            raise ValueError(f"hash table has no op {op!r}")
+        with self._sharded():
+            if op == "insert":
+                keys, vals = self.pools["insert"][slot]
+                out = hashtable.insert(self.state, keys, vals,
+                                       engine=self.engine,
+                                       max_probes=self.max_probes, **kw)
+            elif op == "find":
+                out = hashtable.find(self.state, self.pools["find"][slot],
+                                     engine=self.engine,
+                                     max_probes=self.max_probes, **kw)
+            else:
+                raise ValueError(f"hash table has no op {op!r}")
         return self.plan.per_batch, out
 
     def accept(self, out):

@@ -5,7 +5,8 @@ program's `queue.push_local` under `jax.jit`. The window calls the normal
 front-ends, `queue.push` and `queue.pop`, with `backend="auto"` and an
 `am.AMEngine`, so the default chooser picks the arm of every batch. The
 seed's value base is an argument of every set-up program, so one
-compiled program serves every seed.
+compiled program serves every seed. The ring lives on one chip: a cell
+that asks for more is refused.
 """
 from __future__ import annotations
 
@@ -20,7 +21,12 @@ from repro.core import adaptive, am, queue
 class Driver:
     kind = "queue"
 
-    def __init__(self, config: dict, plan: traffic.Plan):
+    def __init__(self, config: dict, plan: traffic.Plan, devices=None):
+        if devices is not None and len(devices) > 1:
+            raise ValueError(
+                f"the queue driver runs on one chip, not {len(devices)}: "
+                "pushers spread over chips are an open question (PERF.md "
+                "section 7, the queue on 4 chips)")
         self.config, self.plan = config, plan
         self.P = plan.nranks
         self.engine = am.AMEngine(self.P)

@@ -8,7 +8,7 @@ from types import SimpleNamespace
 import jax
 import pytest
 
-from chipbench_toy import CELLS, toy
+from chipbench_toy import CELLS, on_its_chips, toy
 
 from benchmarks.chip import progtrace as pt
 from benchmarks.chip import tracereduce as tr
@@ -122,7 +122,9 @@ def test_program_spans_recorded_on_the_cpu(tmp_path):
 
 
 @pytest.mark.parametrize("name", CELLS)
-def test_toy_windows_off_and_on(name):
+def test_toy_windows_off_and_on(name, request):
+    if on_its_chips(request, name):
+        return
     off = pt.run_window(toy(name), (1 << 31) + 9, 0.3, "off")
     on = pt.run_window(toy(name), (1 << 31) + 9, 0.3, "on")
     assert off["correct"] and on["correct"]

@@ -5,7 +5,8 @@ import jax
 import numpy as np
 import pytest
 
-from chipbench_toy import CELLS, harness, toy
+from chipbench_toy import (CELLS, claim_rule_breaks, harness, on_its_chips,
+                           toy)
 
 from benchmarks.chip import traffic
 
@@ -27,8 +28,9 @@ def _setup_programs(cell, seed, monkeypatch):
         return call
 
     plan = traffic.Plan(cell.config, cell.mix, seed)
-    drv = harness.structure(cell.config["structure"]).Driver(cell.config,
-                                                             plan)
+    devices = jax.devices()[:cell.workload["chips"]]
+    drv = harness.structure(cell.config["structure"]).Driver(
+        cell.config, plan, devices)
     with monkeypatch.context() as m:
         m.setattr(jax, "jit", recording_jit)
         drv.build()
@@ -38,7 +40,10 @@ def _setup_programs(cell, seed, monkeypatch):
 
 
 @pytest.mark.parametrize("name", CELLS)
-def test_setup_programs_do_not_depend_on_the_seed(name, monkeypatch):
+def test_setup_programs_do_not_depend_on_the_seed(name, monkeypatch,
+                                                  request):
+    if on_its_chips(request, name):
+        return
     cell = toy(name)
     a = _setup_programs(cell, (1 << 31) + 77, monkeypatch)
     b = _setup_programs(cell, 3, monkeypatch)
@@ -66,4 +71,25 @@ def test_preloaded_table_holds_every_acknowledged_key():
     assert np.array_equal(found, ok)
     assert np.array_equal(val[ok], traffic.value_np(keys)[ok])
     assert table.ready() == int(ok.sum())
+    drv.free()
+
+
+@pytest.mark.parametrize("chunk", [None, 1 << 9])
+def test_preload_keeps_the_claim_rule(chunk, monkeypatch):
+    """The pre-load in one chunk and in eight: every placed key READY at
+    the slot its probes name with no empty slot before it, every refused
+    key's window full; eight chunks at a load that refuses keys."""
+    from benchmarks.chip.structures import hashtable as drv_ht
+    cell = toy(CELLS[0])
+    cfg = dict(cell.config, nslots=1 << 9)
+    mix = dict(cell.mix, preload_load=0.9)
+    plan = traffic.Plan(cfg, mix, (1 << 31) + 13)
+    if chunk is not None:
+        monkeypatch.setattr(drv_ht, "PRELOAD_CHUNK", chunk)
+        assert plan.n_pre > 4 * chunk
+    drv = drv_ht.Driver(cfg, plan)
+    ok, probes = drv.build()
+    ok = np.asarray(ok)
+    assert 0.5 < ok.mean() < 1
+    assert claim_rule_breaks(drv.state.win.data, cfg, plan, ok, probes) == 0
     drv.free()
